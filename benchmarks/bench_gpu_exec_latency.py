@@ -51,8 +51,8 @@ def _kernel_micro_latency() -> dict:
     key = jax.random.PRNGKey(0)
     ks = jax.random.split(key, 8)
     n_pages = B * pps
-    k_pages = jax.random.normal(ks[0], (n_pages, page, Hk, D))
-    v_pages = jax.random.normal(ks[1], (n_pages, page, Hk, D))
+    k_pages = jax.random.normal(ks[0], (n_pages, Hk, page, D))
+    v_pages = jax.random.normal(ks[1], (n_pages, Hk, page, D))
     table = jnp.arange(n_pages, dtype=jnp.int32).reshape(B, pps)
     seq_lens = jnp.full((B,), 100, dtype=jnp.int32)
     qd = jax.random.normal(ks[2], (B, H, D))

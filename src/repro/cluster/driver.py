@@ -506,16 +506,24 @@ def make_engine_cluster(model_cfg, params, n_replicas: int, *,
                         kv_transfer_base: float = 0.002,
                         kv_transfer_per_token: float = 2e-5,
                         work_stealing: bool = False,
-                        steal_min_depth: int = 4) -> EngineClusterDriver:
+                        steal_min_depth: int = 4,
+                        devices: Optional[Sequence] = None
+                        ) -> EngineClusterDriver:
     """Convenience constructor: N engines over one model's params (the
     common deployment — replicas are copies of the same model), all
-    schedulers sharing one estimator."""
+    schedulers sharing one estimator. ``devices[i]`` holds replica
+    ``i``'s params and KV pool (None: every replica on JAX's default
+    device)."""
+    if devices is not None and len(devices) != n_replicas:
+        raise ValueError(f"need one device per replica: {len(devices)} "
+                         f"devices for {n_replicas} replicas")
     estimator = AdaptiveTokenEstimator(drift_config or DriftConfig())
     engines = [
         ServingEngine(model_cfg, params,
                       DriftScheduler(policy=policy, estimator=estimator),
-                      engine_config)
-        for _ in range(n_replicas)
+                      engine_config,
+                      device=None if devices is None else devices[i])
+        for i in range(n_replicas)
     ]
     return EngineClusterDriver(
         engines, routing=routing, admission=admission, trace=trace,

@@ -100,6 +100,16 @@ def _current_mesh() -> Optional[Mesh]:
         return None
 
 
+def auto_axes(mesh: Mesh) -> Mesh:
+    """``mesh`` with every axis Auto. The model code annotates shardings
+    and lets XLA propagate them; on a mesh with Explicit axes (what
+    ``jax.make_mesh`` builds by default on current JAX) shardings live
+    in the types instead, and the embedding gather fails with a
+    duplicate-axis spec."""
+    return Mesh(mesh.devices, mesh.axis_names,
+                axis_types=(jax.sharding.AxisType.Auto,) * len(mesh.axis_names))
+
+
 def logical_to_spec(
     logical_axes: Sequence[Optional[str]],
     dims: Sequence[int],

@@ -16,7 +16,9 @@ pages matched in the radix tree — with query-offset causal masking:
   ``kv_len`` — so resuming from a cached prefix is just ``q_offset > 0``
   with the prefix pages resident in the table.
 
-Grid = (batch, kv_heads, pages_per_seq); the page axis is last
+The pool is head-major, ``[n_pages, Hk, page_size, D]`` (see
+``paged_attention``). Grid = (batch, kv_heads, pages_per_seq); the page
+axis is last
 (sequential), so the online-softmax scratch — one ``[chunk * group, D]``
 accumulator per (b, kv_head) — persists across pages. Pages wholly above
 the slab's causal frontier or past ``kv_len`` are skipped via ``pl.when``
@@ -45,8 +47,8 @@ def _chunked_prefill_kernel(
     kv_lens_ref,                    # [B] int32 (SMEM)
     # array operands
     q_ref,                          # [1, 1, chunk * group, D]
-    k_ref,                          # [1, page_size, 1, D]
-    v_ref,                          # [1, page_size, 1, D]
+    k_ref,                          # [1, 1, page_size, D]
+    v_ref,                          # [1, 1, page_size, D]
     o_ref,                          # [1, 1, chunk * group, D]
     acc_ref, m_ref, l_ref,          # VMEM scratch
     *,
@@ -76,8 +78,8 @@ def _chunked_prefill_kernel(
     @pl.when((valid > 0) & (page_start < q_off + chunk))
     def _body():
         q = q_ref[0, 0].astype(jnp.float32) * scale   # [chunk * group, D]
-        k = k_ref[0, :, 0, :].astype(jnp.float32)     # [page, D]
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
+        k = k_ref[0, 0].astype(jnp.float32)           # [page, D]
+        v = v_ref[0, 0].astype(jnp.float32)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -111,8 +113,8 @@ def _chunked_prefill_kernel(
 
 def chunked_prefill_attention(
     q: jax.Array,            # [B, chunk, H, D] query slab
-    k_pages: jax.Array,      # [n_pages, page_size, Hk, D]
-    v_pages: jax.Array,      # [n_pages, page_size, Hk, D]
+    k_pages: jax.Array,      # [n_pages, Hk, page_size, D]
+    v_pages: jax.Array,      # [n_pages, Hk, page_size, D]
     page_table: jax.Array,   # [B, pages_per_seq] int32
     q_offsets: jax.Array,    # [B] int32 absolute position of q[:, 0]
     kv_lens: jax.Array,      # [B] int32 resident tokens incl. this slab
@@ -128,7 +130,7 @@ def chunked_prefill_attention(
     + this slab), i.e. normally ``q_offsets + chunk``.
     """
     B, chunk, H, D = q.shape
-    n_pages, page_size, Hk, _ = k_pages.shape
+    n_pages, Hk, page_size, _ = k_pages.shape
     pages_per_seq = page_table.shape[1]
     assert H % Hk == 0
     group = H // Hk
@@ -140,7 +142,7 @@ def chunked_prefill_attention(
 
     def k_index(b, h, p, page_table, q_offsets, kv_lens):
         page = safe_page_index(page_table, kv_lens, b, p, page_size)
-        return (page, 0, h, 0)
+        return (page, h, 0, 0)
 
     def q_index(b, h, p, page_table, q_offsets, kv_lens):
         return (b, h, 0, 0)
@@ -162,8 +164,8 @@ def chunked_prefill_attention(
             grid=(B, Hk, pages_per_seq),
             in_specs=[
                 pl.BlockSpec((1, 1, chunk * group, D), q_index),
-                pl.BlockSpec((1, page_size, 1, D), k_index),
-                pl.BlockSpec((1, page_size, 1, D), k_index),
+                pl.BlockSpec((1, 1, page_size, D), k_index),
+                pl.BlockSpec((1, 1, page_size, D), k_index),
             ],
             out_specs=pl.BlockSpec((1, 1, chunk * group, D), q_index),
             scratch_shapes=[

@@ -11,6 +11,9 @@ TPU-native adaptation of the FlashAttention blocking scheme:
 * online softmax in f32 VREGs; output written once on the final kv step;
 * GQA is handled in the index map (kv block index = q_head // group) —
   no repeated-KV materialisation in HBM;
+* the wrapper lays q/k/v out head-major (``[B, H, L, D]``), so every
+  block is a whole ``[block, D]`` tile: the TPU lowering refuses a
+  size-1 head axis in a block's last two dims;
 * causal + sliding-window masking skips kv blocks that are entirely
   masked (``pl.when`` around the whole body), so the causal case does
   ~L^2/2 work and the windowed case O(L * window).
@@ -80,9 +83,9 @@ def _flash_kernel(
 
     @pl.when(run)
     def _body():
-        q = q_ref[0, :, 0, :].astype(jnp.float32) * scale      # [bq, D]
-        k = k_ref[0, :, 0, :].astype(jnp.float32)              # [bkv, D]
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
+        q = q_ref[0, 0].astype(jnp.float32) * scale           # [bq, D]
+        k = k_ref[0, 0].astype(jnp.float32)                    # [bkv, D]
+        v = v_ref[0, 0].astype(jnp.float32)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -118,7 +121,7 @@ def _flash_kernel(
     def _finalize():
         l = l_ref[:, 0]
         l = jnp.where(l == 0.0, 1.0, l)                        # fully-masked rows
-        o_ref[0, :, 0, :] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
 
 
 def flash_attention(
@@ -167,16 +170,17 @@ def flash_attention(
         n_kv_blocks=nk,
     )
 
+    q, k, v = (x.transpose(0, 2, 1, 3) for x in (q, k, v))   # head-major
     out = pl.pallas_call(
         kernel,
         grid=(B, H, nq, nk),
         in_specs=[
-            pl.BlockSpec((1, block_q, 1, D), lambda b, h, i, j: (b, i, h, 0)),
-            pl.BlockSpec((1, block_kv, 1, D), lambda b, h, i, j: (b, j, h // group, 0)),
-            pl.BlockSpec((1, block_kv, 1, D), lambda b, h, i, j: (b, j, h // group, 0)),
+            pl.BlockSpec((1, 1, block_q, D), lambda b, h, i, j: (b, h, i, 0)),
+            pl.BlockSpec((1, 1, block_kv, D), lambda b, h, i, j: (b, h // group, j, 0)),
+            pl.BlockSpec((1, 1, block_kv, D), lambda b, h, i, j: (b, h // group, j, 0)),
         ],
-        out_specs=pl.BlockSpec((1, block_q, 1, D), lambda b, h, i, j: (b, i, h, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, nq * block_q, H, D), q.dtype),
+        out_specs=pl.BlockSpec((1, 1, block_q, D), lambda b, h, i, j: (b, h, i, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, H, nq * block_q, D), q.dtype),
         scratch_shapes=[
             # f32 VMEM scratch persisted across the sequential kv axis
             pltpu.VMEM((block_q, D), jnp.float32),
@@ -185,4 +189,4 @@ def flash_attention(
         ],
         interpret=interpret,
     )(q, k, v)
-    return out[:, :Lq]
+    return out[:, :, :Lq].transpose(0, 2, 1, 3)

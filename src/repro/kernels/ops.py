@@ -87,8 +87,8 @@ def decode_attention(
 
 def paged_decode_attention(
     q: jax.Array,            # [B, H, D]
-    k_pages: jax.Array,      # [n_pages, page_size, Hk, D]
-    v_pages: jax.Array,      # [n_pages, page_size, Hk, D]
+    k_pages: jax.Array,      # [n_pages, Hk, page_size, D]
+    v_pages: jax.Array,      # [n_pages, Hk, page_size, D]
     page_table: jax.Array,   # [B, pages_per_seq] int32
     seq_lens: jax.Array,     # [B] int32
     *,
@@ -111,8 +111,8 @@ def paged_decode_attention(
 
 def batched_paged_decode_attention(
     q: jax.Array,            # [B, H, D]
-    k_pages: jax.Array,      # [n_pages, page_size, Hk, D]
-    v_pages: jax.Array,      # [n_pages, page_size, Hk, D]
+    k_pages: jax.Array,      # [n_pages, Hk, page_size, D]
+    v_pages: jax.Array,      # [n_pages, Hk, page_size, D]
     page_table: jax.Array,   # [B, pages_per_seq] int32
     seq_lens: jax.Array,     # [B] int32 tokens resident BEFORE this step
     k_new: jax.Array,        # [B, Hk, D] this iteration's key (not in pool)
@@ -146,8 +146,8 @@ def batched_paged_decode_attention(
 
 def chunked_prefill_attention(
     q: jax.Array,            # [B, chunk, H, D] query slab
-    k_pages: jax.Array,      # [n_pages, page_size, Hk, D]
-    v_pages: jax.Array,      # [n_pages, page_size, Hk, D]
+    k_pages: jax.Array,      # [n_pages, Hk, page_size, D]
+    v_pages: jax.Array,      # [n_pages, Hk, page_size, D]
     page_table: jax.Array,   # [B, pages_per_seq] int32
     q_offsets: jax.Array,    # [B] int32 absolute position of q[:, 0]
     kv_lens: jax.Array,      # [B] int32 resident tokens incl. this slab

@@ -18,6 +18,11 @@ data-dependent addressing.
   to a valid page id);
 * one new token per sequence (decode); memory-bound by design.
 
+Pool layout is head-major, ``[n_pages, Hk, page_size, D]``: one grid
+step's K/V block is a whole ``[page_size, D]`` tile of one kv head, so
+the block's last two dims equal the array's — the TPU lowering refuses
+a size-1 head axis in the second-to-last position.
+
 Oracle: ``ref.paged_decode_attention_ref``.
 """
 
@@ -50,8 +55,8 @@ def _paged_kernel(
     seq_lens_ref,                   # [B] int32 (SMEM)
     # array operands
     q_ref,                          # [1, 1, group, D]
-    k_ref,                          # [1, page_size, 1, D]
-    v_ref,                          # [1, page_size, 1, D]
+    k_ref,                          # [1, 1, page_size, D]
+    v_ref,                          # [1, 1, page_size, D]
     o_ref,                          # [1, 1, group, D]
     acc_ref, m_ref, l_ref,          # VMEM scratch
     *,
@@ -75,8 +80,8 @@ def _paged_kernel(
     @pl.when(valid > 0)
     def _body():
         q = q_ref[0, 0].astype(jnp.float32) * scale           # [group, D]
-        k = k_ref[0, :, 0, :].astype(jnp.float32)             # [page, D]
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
+        k = k_ref[0, 0].astype(jnp.float32)                   # [page, D]
+        v = v_ref[0, 0].astype(jnp.float32)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -107,8 +112,8 @@ def _paged_kernel(
 
 def paged_decode_attention(
     q: jax.Array,            # [B, H, D]
-    k_pages: jax.Array,      # [n_pages, page_size, Hk, D]
-    v_pages: jax.Array,      # [n_pages, page_size, Hk, D]
+    k_pages: jax.Array,      # [n_pages, Hk, page_size, D]
+    v_pages: jax.Array,      # [n_pages, Hk, page_size, D]
     page_table: jax.Array,   # [B, pages_per_seq] int32
     seq_lens: jax.Array,     # [B] int32
     *,
@@ -116,7 +121,7 @@ def paged_decode_attention(
     interpret: bool = False,
 ) -> jax.Array:
     B, H, D = q.shape
-    n_pages, page_size, Hk, _ = k_pages.shape
+    n_pages, Hk, page_size, _ = k_pages.shape
     pages_per_seq = page_table.shape[1]
     assert H % Hk == 0
     group = H // Hk
@@ -126,7 +131,7 @@ def paged_decode_attention(
         # clamp to a valid page id when past the sequence end; the body
         # is skipped there, the DMA just needs a legal source.
         page = safe_page_index(page_table, seq_lens, b, p, page_size)
-        return (page, 0, h, 0)
+        return (page, h, 0, 0)
 
     def q_index(b, h, p, page_table, seq_lens):
         return (b, h, 0, 0)
@@ -146,8 +151,8 @@ def paged_decode_attention(
             grid=(B, Hk, pages_per_seq),
             in_specs=[
                 pl.BlockSpec((1, 1, group, D), q_index),
-                pl.BlockSpec((1, page_size, 1, D), k_index),
-                pl.BlockSpec((1, page_size, 1, D), k_index),
+                pl.BlockSpec((1, 1, page_size, D), k_index),
+                pl.BlockSpec((1, 1, page_size, D), k_index),
             ],
             out_specs=pl.BlockSpec((1, 1, group, D), q_index),
             scratch_shapes=[
@@ -168,10 +173,10 @@ def _batched_kernel(
     seq_lens_ref,                   # [B] int32 (SMEM)
     # array operands
     q_ref,                          # [1, 1, group, D]
-    k_ref,                          # [1, page_size, 1, D]
-    v_ref,                          # [1, page_size, 1, D]
-    k_new_ref,                      # [1, 1, D]
-    v_new_ref,                      # [1, 1, D]
+    k_ref,                          # [1, 1, page_size, D]
+    v_ref,                          # [1, 1, page_size, D]
+    k_new_ref,                      # [1, 1, 1, D]
+    v_new_ref,                      # [1, 1, 1, D]
     o_ref,                          # [1, 1, group, D]
     acc_ref, m_ref, l_ref,          # VMEM scratch
     *,
@@ -213,8 +218,8 @@ def _batched_kernel(
 
     @pl.when((p < n_page_steps) & (valid > 0))
     def _page_body():
-        k = k_ref[0, :, 0, :].astype(jnp.float32)             # [page, D]
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
+        k = k_ref[0, 0].astype(jnp.float32)                   # [page, D]
+        v = v_ref[0, 0].astype(jnp.float32)
         s = _softcap(jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -227,8 +232,8 @@ def _batched_kernel(
         # the current iteration's own K/V — not yet resident in the pool,
         # fused here so the kernel never reads a page it aliases with a
         # same-step scatter (position seq_len always attends to itself)
-        k1 = k_new_ref[0].astype(jnp.float32)                  # [1, D]
-        v1 = v_new_ref[0].astype(jnp.float32)
+        k1 = k_new_ref[0, 0].astype(jnp.float32)               # [1, D]
+        v1 = v_new_ref[0, 0].astype(jnp.float32)
         s = _softcap(jax.lax.dot_general(
             q, k1, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -241,8 +246,8 @@ def _batched_kernel(
 
 def batched_paged_decode_attention(
     q: jax.Array,            # [B, H, D]
-    k_pages: jax.Array,      # [n_pages, page_size, Hk, D]
-    v_pages: jax.Array,      # [n_pages, page_size, Hk, D]
+    k_pages: jax.Array,      # [n_pages, Hk, page_size, D]
+    v_pages: jax.Array,      # [n_pages, Hk, page_size, D]
     page_table: jax.Array,   # [B, pages_per_seq] int32
     seq_lens: jax.Array,     # [B] int32 tokens resident BEFORE this step
     k_new: jax.Array,        # [B, Hk, D] this iteration's key (not in pool)
@@ -271,23 +276,24 @@ def batched_paged_decode_attention(
     Oracle: ``ref.batched_paged_decode_attention_ref``.
     """
     B, H, D = q.shape
-    n_pages, page_size, Hk, _ = k_pages.shape
+    n_pages, Hk, page_size, _ = k_pages.shape
     pages_per_seq = page_table.shape[1]
     n_page_steps = pages_per_seq if max_pages is None else max_pages
     assert 1 <= n_page_steps <= pages_per_seq, (n_page_steps, pages_per_seq)
     assert H % Hk == 0
     group = H // Hk
     q_r = q.reshape(B, Hk, group, D)
+    # a unit row axis makes the new token's [1, D] block span the
+    # array's last two dims
+    k_new = k_new.reshape(B, Hk, 1, D)
+    v_new = v_new.reshape(B, Hk, 1, D)
 
     def k_index(b, h, p, page_table, seq_lens):
         page = safe_page_index(page_table, seq_lens, b, p, page_size)
-        return (page, 0, h, 0)
+        return (page, h, 0, 0)
 
     def q_index(b, h, p, page_table, seq_lens):
         return (b, h, 0, 0)
-
-    def new_index(b, h, p, page_table, seq_lens):
-        return (b, h, 0)
 
     kernel = functools.partial(
         _batched_kernel,
@@ -305,10 +311,10 @@ def batched_paged_decode_attention(
             grid=(B, Hk, n_page_steps + 1),
             in_specs=[
                 pl.BlockSpec((1, 1, group, D), q_index),
-                pl.BlockSpec((1, page_size, 1, D), k_index),
-                pl.BlockSpec((1, page_size, 1, D), k_index),
-                pl.BlockSpec((1, 1, D), new_index),
-                pl.BlockSpec((1, 1, D), new_index),
+                pl.BlockSpec((1, 1, page_size, D), k_index),
+                pl.BlockSpec((1, 1, page_size, D), k_index),
+                pl.BlockSpec((1, 1, 1, D), q_index),
+                pl.BlockSpec((1, 1, 1, D), q_index),
             ],
             out_specs=pl.BlockSpec((1, 1, group, D), q_index),
             scratch_shapes=[

@@ -175,10 +175,19 @@ def decode_attention_ref(
     return out.reshape(B, H, D).astype(q.dtype)
 
 
+def gather_pages(pages: jax.Array, page_table: jax.Array) -> jax.Array:
+    """Each sequence's pages of a head-major pool ``[n_pages, Hk,
+    page_size, D]`` as one contiguous ``[B, pages_per_seq * page_size,
+    Hk, D]`` view."""
+    g = pages[page_table]                     # [B, pps, Hk, page, D]
+    B, pps, Hk, page_size, D = g.shape
+    return g.transpose(0, 1, 3, 2, 4).reshape(B, pps * page_size, Hk, D)
+
+
 def paged_decode_attention_ref(
     q: jax.Array,            # [B, H, D]
-    k_pages: jax.Array,      # [n_pages, page_size, Hk, D]  global page pool
-    v_pages: jax.Array,      # [n_pages, page_size, Hk, D]
+    k_pages: jax.Array,      # [n_pages, Hk, page_size, D]  global page pool
+    v_pages: jax.Array,      # [n_pages, Hk, page_size, D]
     page_table: jax.Array,   # [B, pages_per_seq] int32     physical page ids
     seq_lens: jax.Array,     # [B] int32
     *,
@@ -189,11 +198,8 @@ def paged_decode_attention_ref(
     Gathers each sequence's pages into a contiguous view, then defers to
     :func:`decode_attention_ref`.
     """
-    B, H, D = q.shape
-    n_pages, page_size, Hk, _ = k_pages.shape
-    pages_per_seq = page_table.shape[1]
-    k = k_pages[page_table].reshape(B, pages_per_seq * page_size, Hk, D)
-    v = v_pages[page_table].reshape(B, pages_per_seq * page_size, Hk, D)
+    k = gather_pages(k_pages, page_table)
+    v = gather_pages(v_pages, page_table)
     return decode_attention_ref(
         q, k, v, seq_lens, logit_softcap=logit_softcap
     )
@@ -201,8 +207,8 @@ def paged_decode_attention_ref(
 
 def batched_paged_decode_attention_ref(
     q: jax.Array,            # [B, H, D]
-    k_pages: jax.Array,      # [n_pages, page_size, Hk, D]
-    v_pages: jax.Array,      # [n_pages, page_size, Hk, D]
+    k_pages: jax.Array,      # [n_pages, Hk, page_size, D]
+    v_pages: jax.Array,      # [n_pages, Hk, page_size, D]
     page_table: jax.Array,   # [B, pages_per_seq] int32
     seq_lens: jax.Array,     # [B] int32 tokens resident BEFORE this step
     k_new: jax.Array,        # [B, Hk, D]
@@ -213,13 +219,9 @@ def batched_paged_decode_attention_ref(
     """Oracle for the batched decode kernel: scatter the new token into
     the gathered contiguous view at position ``seq_lens[b]``, then attend
     over ``seq_lens + 1`` tokens."""
-    B, H, D = q.shape
-    n_pages, page_size, Hk, _ = k_pages.shape
-    pages_per_seq = page_table.shape[1]
-    S = pages_per_seq * page_size
-    k = k_pages[page_table].reshape(B, S, Hk, D)
-    v = v_pages[page_table].reshape(B, S, Hk, D)
-    rows = jnp.arange(B)
+    k = gather_pages(k_pages, page_table)
+    v = gather_pages(v_pages, page_table)
+    rows = jnp.arange(q.shape[0])
     k = k.at[rows, seq_lens].set(k_new.astype(k.dtype))
     v = v.at[rows, seq_lens].set(v_new.astype(v.dtype))
     return decode_attention_ref(
@@ -229,8 +231,8 @@ def batched_paged_decode_attention_ref(
 
 def chunked_prefill_attention_ref(
     q: jax.Array,            # [B, chunk, H, D] query slab
-    k_pages: jax.Array,      # [n_pages, page_size, Hk, D]
-    v_pages: jax.Array,      # [n_pages, page_size, Hk, D]
+    k_pages: jax.Array,      # [n_pages, Hk, page_size, D]
+    v_pages: jax.Array,      # [n_pages, Hk, page_size, D]
     page_table: jax.Array,   # [B, pages_per_seq] int32
     q_offsets: jax.Array,    # [B] int32 absolute position of q[:, 0]
     kv_lens: jax.Array,      # [B] int32 resident tokens incl. this slab
@@ -242,12 +244,10 @@ def chunked_prefill_attention_ref(
     masking at absolute positions (query row i sits at position
     ``q_offsets[b] + i``; rows past ``kv_lens`` come back as zeros)."""
     B, chunk, H, D = q.shape
-    n_pages, page_size, Hk, _ = k_pages.shape
-    pages_per_seq = page_table.shape[1]
-    S = pages_per_seq * page_size
+    k = gather_pages(k_pages, page_table).astype(jnp.float32)
+    v = gather_pages(v_pages, page_table).astype(jnp.float32)
+    S, Hk = k.shape[1], k.shape[2]
     group = H // Hk
-    k = k_pages[page_table].reshape(B, S, Hk, D).astype(jnp.float32)
-    v = v_pages[page_table].reshape(B, S, Hk, D).astype(jnp.float32)
     qf = (q.astype(jnp.float32) * (D ** -0.5)).reshape(B, chunk, Hk, group, D)
     s = jnp.einsum("bqhgd,bshd->bhgqs", qf, k)            # [B,Hk,g,chunk,S]
     if logit_softcap is not None:

@@ -55,7 +55,8 @@ def lower_cell(cfg: ModelConfig, shape_name: str, mesh, *,
     cell_shardings.params_shardings_for). ``batch_mode``: default |
     dp-all (batch over the model axis too; activation constraints switch
     to the pure-DP rule set)."""
-    from ..distributed.sharding import logical_mode
+    from ..distributed.sharding import auto_axes, logical_mode
+    mesh = auto_axes(mesh)
     shape = SHAPES[shape_name]
     specs = input_specs(cfg, shape)
     aparams = abstract_params(cfg)
@@ -79,7 +80,7 @@ def lower_cell(cfg: ModelConfig, shape_name: str, mesh, *,
                 cs.attach(aopt, o_shard),
                 cs.attach(specs, b_shard))
         jitted = jax.jit(step, donate_argnums=(0, 1) if donate else ())
-        with logical_mode(rules):
+        with mesh, logical_mode(rules):
             lowered = jitted.lower(*args)
         meta["param_policy"] = policy
         meta["n_tokens"] = shape.global_batch * shape.seq_len
@@ -97,7 +98,7 @@ def lower_cell(cfg: ModelConfig, shape_name: str, mesh, *,
         args = (cs.attach(aparams, p_shard),
                 cs.attach(specs, b_shard),
                 rng)
-        with logical_mode(rules):
+        with mesh, logical_mode(rules):
             lowered = jax.jit(step).lower(*args)
         meta["n_tokens"] = shape.global_batch * shape.seq_len
         return lowered, meta
@@ -112,7 +113,7 @@ def lower_cell(cfg: ModelConfig, shape_name: str, mesh, *,
             specs["pos"],
             specs["rng"])
     jitted = jax.jit(step, donate_argnums=(1,) if donate else ())
-    with logical_mode(rules):
+    with mesh, logical_mode(rules):
         lowered = jitted.lower(*args)
     meta["n_tokens"] = shape.global_batch  # one new token per sequence
     return lowered, meta

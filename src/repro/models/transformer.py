@@ -213,18 +213,18 @@ def decode_step_paged(cfg: ModelConfig, params: Dict, pool: Dict,
     ``seq_lens[b]`` only for the pool carry. ``max_pages`` statically
     trims the kernel's page grid to the deepest live sequence.
 
-    pool: {"k": [L, n_pages, page, Hk, hd], "v": ...}.
+    pool: {"k": [L, n_pages, Hk, page, hd], "v": ...}.
     Returns (logits, new_pool)."""
     from ..kernels import ops
     B = tokens.shape[0]
-    page_size = pool["k"].shape[2]
+    page_size = pool["k"].shape[3]
     x = nn.embed(cfg, params["embed"], tokens)        # [B, d]
     page_idx = seq_lens // page_size
     offset = seq_lens % page_size
     phys = jnp.take_along_axis(page_table, page_idx[:, None], axis=1)[:, 0]
 
     def scan_body(h, xs):
-        lp, kp, vp = xs                                # [n_pages, page, Hk, hd]
+        lp, kp, vp = xs                                # [n_pages, Hk, page, hd]
         h = constrain(h, "batch", "model")
         attn_in = nn.apply_norm(cfg, lp["ln1"], h)
         q = jnp.einsum("bd,dhk->bhk", attn_in, lp["attn"]["wq"])
@@ -236,8 +236,8 @@ def decode_step_paged(cfg: ModelConfig, params: Dict, pool: Dict,
         attn = ops.batched_paged_decode_attention(
             q, kp, vp, page_table, seq_lens, k_new, v_new,
             max_pages=max_pages, logit_softcap=cfg.logit_softcap)
-        kp = kp.at[phys, offset].set(k_new.astype(kp.dtype))
-        vp = vp.at[phys, offset].set(v_new.astype(vp.dtype))
+        kp = kp.at[phys, :, offset].set(k_new.astype(kp.dtype))
+        vp = vp.at[phys, :, offset].set(v_new.astype(vp.dtype))
         h = h + jnp.einsum("bhk,hkd->bd", attn, lp["attn"]["wo"])
         mlp_in = nn.apply_norm(cfg, lp["ln2"], h)
         if cfg.family == "moe":
@@ -267,11 +267,11 @@ def prefill_chunk_paged(cfg: ModelConfig, params: Dict, pool: Dict,
     calls — with query-offset causal masking. Resuming from a cached
     prefix is just starting at ``q_offset > 0``.
 
-    pool: {"k": [L, n_pages, page, Hk, hd], "v": ...}.
+    pool: {"k": [L, n_pages, Hk, page, hd], "v": ...}.
     Returns (last-position logits [B, V], new_pool)."""
     from ..kernels import ops
     B, C = tokens.shape
-    page_size = pool["k"].shape[2]
+    page_size = pool["k"].shape[3]
     x = nn.embed(cfg, params["embed"], tokens)           # [B, C, d]
     positions = q_offset[:, None] + jnp.arange(C)[None]  # [B, C]
     phys = jnp.take_along_axis(page_table, positions // page_size, axis=1)
@@ -279,15 +279,15 @@ def prefill_chunk_paged(cfg: ModelConfig, params: Dict, pool: Dict,
     kv_lens = q_offset + C
 
     def scan_body(h, xs):
-        lp, kp, vp = xs                                # [n_pages, page, Hk, hd]
+        lp, kp, vp = xs                                # [n_pages, Hk, page, hd]
         h = constrain(h, "batch", None, "residual")
         attn_in = nn.apply_norm(cfg, lp["ln1"], h)
         q, k, v = nn.qkv_project(lp["attn"], attn_in)  # [B, C, H/Hk, hd]
         if cfg.pos == "rope":
             q = nn.apply_rope(q, positions, cfg.rope_theta)
             k = nn.apply_rope(k, positions, cfg.rope_theta)
-        kp = kp.at[phys, offset].set(k.astype(kp.dtype))
-        vp = vp.at[phys, offset].set(v.astype(vp.dtype))
+        kp = kp.at[phys, :, offset].set(k.astype(kp.dtype))
+        vp = vp.at[phys, :, offset].set(v.astype(vp.dtype))
         attn = ops.chunked_prefill_attention(
             q, kp, vp, page_table, q_offset, kv_lens,
             logit_softcap=cfg.logit_softcap)

@@ -36,8 +36,8 @@ ADMIT = "admit"                   # admission accepted (est priced)
 SHED = "shed"                     # admission rejected (data: reason)
 ROUTE = "route"                   # router placed it (data: stage)
 PREFILL_CHUNK = "prefill_chunk"   # one prompt chunk consumed (data: tokens)
-FIRST_TOKEN = "first_token"       # honest TTFT anchor (data: ttft)
-DECODE_STEP = "decode_step"       # one decode token (ring-sampled)
+FIRST_TOKEN = "first_token"       # honest TTFT anchor (data: ttft[, token])
+DECODE_STEP = "decode_step"       # one decode token (data: n[, token])
 HANDOFF = "handoff"               # P/D KV transfer (data: edge=out|in)
 STEAL = "steal"                   # work stealing moved it (victim/thief)
 PREFIX_HIT = "prefix_hit"         # joined with resident prefix pages

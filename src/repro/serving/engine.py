@@ -81,7 +81,7 @@ from ..models.registry import get_api
 from ..models.steps import sample_logits
 from ..obs import events as tr
 from ..obs import resolve_recorder
-from .kv_cache import PagedSeqLedger, prefix_page_key
+from .kv_cache import PagedPool, PagedSeqLedger, prefix_page_key
 from .metrics import RunMetrics, summarize_run
 
 
@@ -133,14 +133,19 @@ class SlotState:
 
 
 class ServingEngine:
-    """Continuous-batching engine for one model on the local backend."""
+    """Continuous-batching engine for one model on one device.
+
+    ``device`` is where the engine's params and KV cache live (None:
+    JAX's default device, arrays placed as the caller made them); every
+    jitted step follows them there."""
 
     def __init__(self, cfg: ModelConfig, params, scheduler: DriftScheduler,
                  config: Optional[EngineConfig] = None,
                  extras: Optional[Dict] = None,
-                 trace=None) -> None:
+                 trace=None, device: Optional[jax.Device] = None) -> None:
         self.cfg = cfg
-        self.params = params
+        self.params = (params if device is None
+                       else jax.device_put(params, device))
         self.sched = scheduler
         self.ecfg = config or EngineConfig()
         self.extras = extras or {}
@@ -187,7 +192,7 @@ class ServingEngine:
                 raise ValueError(
                     f"paged engine supports transformer-family archs, "
                     f"not {cfg.family!r} (SSM state is O(1) already)")
-            from .kv_cache import PagedAllocator, PagedPool, PrefixTree
+            from .kv_cache import PagedAllocator, PrefixTree
             pages_per_seq = -(-S // self.ecfg.page_size)
             extra = (self.ecfg.prefix_cache_pages
                      if self.ecfg.prefix_cache else 0)
@@ -204,11 +209,13 @@ class ServingEngine:
                 cache_pages_budget=(self.ecfg.prefix_cache_pages
                                     if self.ecfg.prefix_cache else None))
             self.pool = PagedPool.create(cfg, self.alloc.n_pages + 1,
-                                         self.ecfg.page_size)
+                                         self.ecfg.page_size, device=device)
             self._decode_paged = jax.jit(self._decode_paged_fn,
                                          static_argnames=("max_pages",))
         else:
             self.cache = self.api.init_cache(cfg, n, S)
+            if device is not None:
+                self.cache = jax.device_put(self.cache, device)
             self._decode = jax.jit(self._decode_fn)
 
     # --- jitted units ---------------------------------------------------
@@ -333,7 +340,7 @@ class ServingEngine:
                 tokens[0, shared_eff:] = reps % vocab + 1
         else:
             ids = np.frombuffer(req.prompt.encode()[:prompt_len * 4],
-                                dtype=np.uint8)[:prompt_len]
+                                dtype=np.uint8)[:prompt_len].astype(np.int64)
             if len(ids):
                 tokens[0, -len(ids):] = ids % vocab + 1
         return tokens
@@ -461,7 +468,6 @@ class ServingEngine:
             self.params, {"k": self.pool.k, "v": self.pool.v},
             st.batch["tokens"][:, start:end], pt,
             jnp.asarray([start], jnp.int32))
-        from .kv_cache import PagedPool
         self.pool = PagedPool(k=new_pool["k"], v=new_pool["v"],
                               page_size=self.ecfg.page_size)
         self.n_prefill_launches += 1
@@ -489,7 +495,7 @@ class ServingEngine:
             self.trace.emit(now, tr.FIRST_TOKEN, req_id=st.req.req_id,
                             rid=self.trace_rid,
                             tenant=st.req.tenant.label,
-                            ttft=now - st.req.arrival_time)
+                            ttft=now - st.req.arrival_time, token=tok)
         if self.handoff_hook is not None \
                 and self.handoff_hook(slot, st.req, now):
             self._release_slot(slot)
@@ -554,12 +560,12 @@ class ServingEngine:
         assert self.ecfg.paged, "KV injection requires the paged pool"
         self.ledger.admit(slot, payload["seq_len"], (), now)
         pages = jnp.asarray(self.ledger.table(slot), jnp.int32)
-        from .kv_cache import PagedPool
+        # the payload is host memory; land it on this engine's device
+        k, v = jax.device_put((payload["k"], payload["v"]),
+                              self.pool.k.sharding)
         self.pool = PagedPool(
-            k=self.pool.k.at[:, pages].set(
-                jnp.asarray(payload["k"]).astype(self.pool.k.dtype)),
-            v=self.pool.v.at[:, pages].set(
-                jnp.asarray(payload["v"]).astype(self.pool.v.dtype)),
+            k=self.pool.k.at[:, pages].set(k.astype(self.pool.k.dtype)),
+            v=self.pool.v.at[:, pages].set(v.astype(self.pool.v.dtype)),
             page_size=self.ecfg.page_size)
         st = self.slots[slot]
         st.req = req
@@ -692,12 +698,28 @@ class ServingEngine:
             sids = [i if (self.slots[i].req is not None
                           and not self.slots[i].pending_prefill) else None
                     for i in range(self.ecfg.n_slots)]
+            lens = self.ledger.lens_array(sids)  # tokens before this step
+            for i in decoding:
+                # grow the table first: this step writes the token's KV
+                # at position lens[i], which opens a new page whenever
+                # lens[i] is page-aligned
+                _, cows = self.ledger.extend(i, 1)
+                for old, new in cows:
+                    # copy-on-write boundary: the ledger handed this
+                    # slot a private copy of a shared page — mirror it
+                    # device-side before this step's write lands there.
+                    # Unreachable with full-page prefix keys (suffix
+                    # pages are always private) but wired for the
+                    # partial-page layouts cow_extend exists for.
+                    self.pool = PagedPool(
+                        k=self.pool.k.at[:, new].set(self.pool.k[:, old]),
+                        v=self.pool.v.at[:, new].set(self.pool.v[:, old]),
+                        page_size=self.ecfg.page_size)
             pt = self.ledger.table_array(sids, self.alloc.pages_per_seq)
             scratch = self.pool.n_pages - 1      # never allocated: inactive
             for i, sid in enumerate(sids):       # slots write there
                 if sid is None:
                     pt[i, :] = scratch
-            lens = self.ledger.lens_array(sids)
             # static page-grid trim for the batched kernel: next power
             # of two above the deepest live sequence (bounded set of
             # jit variants), clamped to the table width
@@ -710,22 +732,8 @@ class ServingEngine:
                 self.params, {"k": self.pool.k, "v": self.pool.v},
                 jnp.asarray(tokens), jnp.asarray(pt),
                 jnp.asarray(lens), sub, max_pages=max_pages)
-            from .kv_cache import PagedPool
             self.pool = PagedPool(k=new_pool["k"], v=new_pool["v"],
                                   page_size=self.ecfg.page_size)
-            for i in decoding:
-                _, cows = self.ledger.extend(i, 1)
-                for old, new in cows:
-                    # copy-on-write boundary: the ledger handed this
-                    # slot a private copy of a shared page — mirror it
-                    # device-side before the next write lands there.
-                    # Unreachable with full-page prefix keys (suffix
-                    # pages are always private) but wired for the
-                    # partial-page layouts cow_extend exists for.
-                    self.pool = PagedPool(
-                        k=self.pool.k.at[:, new].set(self.pool.k[:, old]),
-                        v=self.pool.v.at[:, new].set(self.pool.v[:, old]),
-                        page_size=self.ecfg.page_size)
         else:
             pos = np.asarray(self.cache["lens"])     # per-slot depth
             toks, self.cache = self._decode(
@@ -742,7 +750,7 @@ class ServingEngine:
             if tron:
                 self.trace.emit(now, tr.DECODE_STEP,
                                 req_id=st.req.req_id, rid=self.trace_rid,
-                                n=st.generated)
+                                n=st.generated, token=st.last_token)
             if st.generated >= st.target:       # oracle EOS
                 self._retire(i, now)
                 done += 1

@@ -42,7 +42,11 @@ if jax is not None:
 
 @dataclass
 class PagedPool:
-    """Device-side page pool for one model: [L, n_pages, page, Hk, hd]."""
+    """Device-side page pool for one model: [L, n_pages, Hk, page, hd].
+
+    Head-major within a page, so the paged kernels fetch one kv head's
+    whole ``[page, hd]`` tile per grid step (the TPU lowering's block
+    rule); the page axis stays at position 1 for page gathers/scatters."""
 
     k: "jax.Array"
     v: "jax.Array"
@@ -50,12 +54,14 @@ class PagedPool:
 
     @classmethod
     def create(cls, cfg: "ModelConfig", n_pages: int, page_size: int = 128,
-               dtype=None) -> "PagedPool":
+               dtype=None, device=None) -> "PagedPool":
+        """Zeroed pool on ``device`` (None: JAX's default device)."""
         if jnp is None:  # pragma: no cover
             raise ImportError("PagedPool.create requires JAX")
         dtype = dtype or jnp.dtype(cfg.dtype)
-        shape = (cfg.n_layers, n_pages, page_size, cfg.n_kv_heads, cfg.d_head)
-        return cls(k=jnp.zeros(shape, dtype), v=jnp.zeros(shape, dtype),
+        shape = (cfg.n_layers, n_pages, cfg.n_kv_heads, page_size, cfg.d_head)
+        return cls(k=jnp.zeros(shape, dtype, device=device),
+                   v=jnp.zeros(shape, dtype, device=device),
                    page_size=page_size)
 
     @property
@@ -772,6 +778,7 @@ def write_prefill_pages(pool: PagedPool, layer_kv: Tuple["jax.Array", "jax.Array
             pad = P - (hi - lo)
             chunk_k = jnp.pad(chunk_k, ((0, 0), (0, pad), (0, 0), (0, 0)))
             chunk_v = jnp.pad(chunk_v, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        k = k.at[:, page].set(chunk_k.astype(k.dtype))
-        v = v.at[:, page].set(chunk_v.astype(v.dtype))
+        # [L, page, Hk, hd] -> the pool's head-major [L, Hk, page, hd]
+        k = k.at[:, page].set(chunk_k.transpose(0, 2, 1, 3).astype(k.dtype))
+        v = v.at[:, page].set(chunk_v.transpose(0, 2, 1, 3).astype(v.dtype))
     return PagedPool(k=k, v=v, page_size=P)

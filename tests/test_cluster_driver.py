@@ -64,3 +64,48 @@ def test_engine_cluster_admission_sheds():
     assert driver.n_shed == 12 - accepted
     m = driver.run_until_drained(max_steps=5000)
     assert m.n_completed == accepted
+
+
+def _pd_run(devices):
+    """A seeded P/D run over two paged replicas placed on ``devices``:
+    completions, routing and handoff counts, and the replicas' pools."""
+    cfg = smoke_config("smollm-135m")
+    params = get_api(cfg).init(cfg, jax.random.PRNGKey(0))
+    driver = make_engine_cluster(
+        cfg, params, 2, routing="pd_disaggregated", devices=devices,
+        engine_config=EngineConfig(n_slots=2, max_len=96,
+                                   prompt_buckets=(16,), paged=True,
+                                   page_size=8, chunk_prefill_tokens=8))
+    gen = WorkloadGenerator(GeneratorConfig(
+        total_requests=6, calibration_requests=6, max_tokens=24, seed=3))
+    reqs = [r for t, r in gen.plan(seed=3).calibration
+            if driver.submit(r, t)]
+    assert driver.run_until_drained(max_steps=5000).n_completed == 6
+    pos = {r.req_id: i for i, r in enumerate(reqs)}
+    seen = {
+        "completions": sorted((r.completion_time, pos[r.req_id])
+                              for r in reqs),
+        "routing": [(r.prefill_rid, r.decode_rid) for r in reqs],
+        "handoffs": driver.n_handoffs,
+    }
+    return seen, [rep.engine.pool for rep in driver.replicas]
+
+
+def test_engine_cluster_places_replicas_on_devices():
+    """Replica i's params and pool live on devices[i], KV handed off
+    lands there too, and placement changes no scheduling outcome."""
+    dev = jax.devices()[0]
+    placed, pools = _pd_run([dev, dev])
+    default, _ = _pd_run(None)
+    assert placed == default
+    assert placed["handoffs"] == 6
+    for pool in pools:
+        assert pool.k.devices() == {dev} and pool.v.devices() == {dev}
+        assert pool.k.committed
+
+
+def test_engine_cluster_needs_one_device_per_replica():
+    cfg = smoke_config("smollm-135m")
+    params = get_api(cfg).init(cfg, jax.random.PRNGKey(0))
+    with pytest.raises(ValueError, match="one device per replica"):
+        make_engine_cluster(cfg, params, 2, devices=jax.devices()[:1])

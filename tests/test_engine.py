@@ -118,6 +118,52 @@ def test_paged_engine_matches_contiguous_completions():
     assert eng_p.alloc.free_pages == eng_p.alloc.n_pages  # all freed
 
 
+@pytest.mark.parametrize("prefix_cache", [False, True])
+def test_paged_decode_ignores_stale_pool_pages(prefix_cache):
+    """Every decode step's KV lands in a page of its own sequence, also
+    when it opens a new page: a pool full of stale values samples the
+    same tokens as a fresh one."""
+    import jax.numpy as jnp
+
+    from repro.obs import TraceRecorder
+    from repro.obs import events as tr
+    from repro.serving.kv_cache import PagedPool
+    cfg = smoke_config("smollm-135m")
+    params = get_api(cfg).init(cfg, jax.random.PRNGKey(0))
+
+    class TokenLog:
+        def __init__(self):
+            self.tokens = {}
+
+        def on_event(self, ev):
+            if ev.kind in (tr.FIRST_TOKEN, tr.DECODE_STEP):
+                self.tokens.setdefault(ev.req_id, []).append(
+                    ev.data["token"])
+
+    def run(stale):
+        log = TokenLog()
+        rec = TraceRecorder(capacity=1, observers=[log])
+        sched = DriftScheduler(policy="fifo")
+        eng = ServingEngine(cfg, params, sched,
+                            EngineConfig(n_slots=3, max_len=96,
+                                         prompt_buckets=(16,), paged=True,
+                                         page_size=8, chunk_prefill_tokens=8,
+                                         prefix_cache=prefix_cache),
+                            trace=rec)
+        if stale:
+            eng.pool = PagedPool(k=jnp.full_like(eng.pool.k, 1e4),
+                                 v=jnp.full_like(eng.pool.v, 1e4),
+                                 page_size=eng.pool.page_size)
+        reqs = _submit_n(sched, 6, seed=3)
+        eng.run_until_drained(max_steps=5000)
+        # by plan position: request ids differ between two plans
+        return [log.tokens[r.req_id] for r in reqs]
+
+    fresh = run(stale=False)
+    assert max(map(len, fresh)) > 8            # decode crosses a page
+    assert run(stale=True) == fresh
+
+
 def test_paged_engine_rejects_ssm():
     import pytest as _pytest
     cfg = smoke_config("mamba2-2.7b")
@@ -126,3 +172,21 @@ def test_paged_engine_rejects_ssm():
     with _pytest.raises(ValueError):
         ServingEngine(cfg, params, DriftScheduler(policy="fifo"),
                       EngineConfig(paged=True))
+
+
+def test_tokenize_at_a_published_vocab():
+    """Prompt bytes map to ids in [1, vocab) at a vocab wider than a
+    byte: the legacy layout right-aligns them, padding in front."""
+    import dataclasses
+    cfg = dataclasses.replace(smoke_config("smollm-135m"), vocab=49152)
+    params = get_api(cfg).init(cfg, jax.random.PRNGKey(0))
+    eng = ServingEngine(cfg, params, DriftScheduler(),
+                        EngineConfig(n_slots=1, max_len=32,
+                                     prompt_buckets=(16,)))
+    req = Request(tenant=TenantTier.STANDARD, category=Category.SHORT_QA,
+                  prompt="\xff hi", max_tokens=4)
+    tokens = eng._tokenize(req, 16, 4, 0)
+    want = np.frombuffer(req.prompt.encode()[:4], np.uint8) + 1
+    assert tokens.dtype == np.int32
+    np.testing.assert_array_equal(tokens[0, -4:], want)
+    assert not tokens[0, :-4].any()

@@ -122,8 +122,8 @@ def test_paged_decode_attention(B, H, Hk, D, page, pages_per_seq, dtype):
     ks = jax.random.split(key, 4)
     n_pages = B * pages_per_seq + 3
     q = _rand(ks[0], (B, H, D), dtype)
-    k_pages = _rand(ks[1], (n_pages, page, Hk, D), dtype)
-    v_pages = _rand(ks[2], (n_pages, page, Hk, D), dtype)
+    k_pages = _rand(ks[1], (n_pages, Hk, page, D), dtype)
+    v_pages = _rand(ks[2], (n_pages, Hk, page, D), dtype)
     # each sequence gets a random non-overlapping page set
     perm = jax.random.permutation(ks[3], n_pages)[:B * pages_per_seq]
     page_table = perm.reshape(B, pages_per_seq).astype(jnp.int32)
@@ -147,9 +147,9 @@ def test_paged_equals_contiguous():
     k_cache = _rand(ks[1], (B, S, Hk, D), jnp.float32)
     v_cache = _rand(ks[2], (B, S, Hk, D), jnp.float32)
     lens = jnp.array([37, 61], jnp.int32)
-    # lay the contiguous cache into pages
-    k_pages = k_cache.reshape(B * pps, page, Hk, D)
-    v_pages = v_cache.reshape(B * pps, page, Hk, D)
+    # lay the contiguous cache into head-major pages
+    k_pages = k_cache.reshape(B * pps, page, Hk, D).transpose(0, 2, 1, 3)
+    v_pages = v_cache.reshape(B * pps, page, Hk, D).transpose(0, 2, 1, 3)
     page_table = jnp.arange(B * pps, dtype=jnp.int32).reshape(B, pps)
     got = ref.paged_decode_attention_ref(q, k_pages, v_pages, page_table, lens)
     expect = ref.decode_attention_ref(q, k_cache, v_cache, lens)
@@ -162,11 +162,12 @@ def test_paged_equals_contiguous():
 # ---------------------------------------------------------------------------
 
 def _page_scene(key, B, Hk, D, page, pps, dtype, extra=3):
-    """Random pool + non-overlapping per-sequence page tables."""
+    """Random head-major pool + non-overlapping per-sequence page
+    tables."""
     ks = jax.random.split(key, 3)
     n_pages = B * pps + extra
-    k_pages = _rand(ks[0], (n_pages, page, Hk, D), dtype)
-    v_pages = _rand(ks[1], (n_pages, page, Hk, D), dtype)
+    k_pages = _rand(ks[0], (n_pages, Hk, page, D), dtype)
+    v_pages = _rand(ks[1], (n_pages, Hk, page, D), dtype)
     perm = jax.random.permutation(ks[2], n_pages)[:B * pps]
     page_table = perm.reshape(B, pps).astype(jnp.int32)
     return k_pages, v_pages, page_table
@@ -192,8 +193,8 @@ def test_batched_paged_decode_vs_per_sequence(dtype, max_pages):
     # per-sequence baseline: scatter the new token, then one legacy
     # kernel call per sequence over seq_lens + 1 tokens
     phys = page_table[jnp.arange(B), seq_lens // page]
-    k_sc = k_pages.at[phys, seq_lens % page].set(k_new)
-    v_sc = v_pages.at[phys, seq_lens % page].set(v_new)
+    k_sc = k_pages.at[phys, :, seq_lens % page].set(k_new)
+    v_sc = v_pages.at[phys, :, seq_lens % page].set(v_new)
     for b in range(B):
         single = paged_decode_attention(
             q[b:b + 1], k_sc, v_sc, page_table[b:b + 1],
@@ -291,14 +292,14 @@ def test_chunked_prefill_resumption_matches_full_causal(chunk):
     v_full = _rand(ks[2], (1, T, Hk, D), jnp.float32)
     perm = jax.random.permutation(ks[3], n_pages)[:pps].astype(jnp.int32)
     page_table = perm[None, :]
-    k_pages = jnp.zeros((n_pages, page, Hk, D), jnp.float32)
+    k_pages = jnp.zeros((n_pages, Hk, page, D), jnp.float32)
     v_pages = jnp.zeros_like(k_pages)
     outs = []
     for s in range(0, T, chunk):
         # the caller's contract: scatter the slab's K/V first...
         for t in range(s, s + chunk):
-            k_pages = k_pages.at[perm[t // page], t % page].set(k_full[0, t])
-            v_pages = v_pages.at[perm[t // page], t % page].set(v_full[0, t])
+            k_pages = k_pages.at[perm[t // page], :, t % page].set(k_full[0, t])
+            v_pages = v_pages.at[perm[t // page], :, t % page].set(v_full[0, t])
         # ...then attend it against everything resident so far
         outs.append(chunked_prefill_attention(
             q_full[:, s:s + chunk], k_pages, v_pages, page_table,

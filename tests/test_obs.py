@@ -503,6 +503,32 @@ def test_engine_trace_lifecycles_valid():
     assert rec.stats()["segments"] == ["engine:fifo"]
 
 
+def test_engine_trace_carries_sampled_tokens():
+    """Observers see every sampled token: the first from first_token,
+    the rest from decode_step, one per output token counted."""
+    tokens, counted = {}, {}
+
+    class Tokens:
+        def on_event(self, ev):
+            if ev.kind in (tr.FIRST_TOKEN, tr.DECODE_STEP):
+                tokens.setdefault(ev.req_id, []).append(ev.data["token"])
+                if ev.kind == tr.DECODE_STEP:
+                    assert ev.data["n"] == len(tokens[ev.req_id])
+            elif ev.kind == tr.COMPLETE:
+                counted[ev.req_id] = ev.data["observed"]
+
+    rec = TraceRecorder(capacity=1, observers=[Tokens()])
+    sched, m = _engine_run(trace=rec)
+    assert m.n_completed == len(counted) == len(tokens) == 8
+    assert {r: len(t) for r, t in tokens.items()} == counted
+    assert all(0 <= t < 256 for ts in tokens.values() for t in ts)
+    # greedy decoding: a second run samples the same tokens
+    again = dict(tokens)
+    tokens.clear()
+    _engine_run(trace=rec)
+    assert sorted(again.values()) == sorted(tokens.values())
+
+
 def test_engine_traced_identical_to_untraced():
     sched_a, m_a = _engine_run(trace=None)
     rec = TraceRecorder(sample_every=FULL)
