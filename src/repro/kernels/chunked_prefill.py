@@ -176,6 +176,7 @@ def chunked_prefill_attention(
         ),
         out_shape=jax.ShapeDtypeStruct((B, Hk, chunk * group, D), q.dtype),
         interpret=interpret,
+        name="chunked_prefill_attention",
     )(page_table, q_offsets, kv_lens, q_r, k_pages, v_pages)
     return (out.reshape(B, Hk, chunk, group, D)
                .transpose(0, 2, 1, 3, 4)

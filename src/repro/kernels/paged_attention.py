@@ -163,6 +163,7 @@ def paged_decode_attention(
         ),
         out_shape=jax.ShapeDtypeStruct((B, Hk, group, D), q.dtype),
         interpret=interpret,
+        name="paged_decode_attention",
     )(page_table, seq_lens, q_r, k_pages, v_pages)
     return out.reshape(B, H, D)
 
@@ -325,5 +326,6 @@ def batched_paged_decode_attention(
         ),
         out_shape=jax.ShapeDtypeStruct((B, Hk, group, D), q.dtype),
         interpret=interpret,
+        name="batched_paged_decode_attention",
     )(page_table, seq_lens, q_r, k_pages, v_pages, k_new, v_new)
     return out.reshape(B, H, D)

@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 
 from ..distributed.sharding import constrain
+from ..obs import spans
 from . import layers as nn
 from .config import ModelConfig
 
@@ -226,31 +227,36 @@ def decode_step_paged(cfg: ModelConfig, params: Dict, pool: Dict,
     def scan_body(h, xs):
         lp, kp, vp = xs                                # [n_pages, Hk, page, hd]
         h = constrain(h, "batch", "model")
-        attn_in = nn.apply_norm(cfg, lp["ln1"], h)
-        q = jnp.einsum("bd,dhk->bhk", attn_in, lp["attn"]["wq"])
-        k_new = jnp.einsum("bd,dhk->bhk", attn_in, lp["attn"]["wk"])
-        v_new = jnp.einsum("bd,dhk->bhk", attn_in, lp["attn"]["wv"])
-        if cfg.pos == "rope":
-            q = nn.apply_rope(q, seq_lens, cfg.rope_theta)
-            k_new = nn.apply_rope(k_new, seq_lens, cfg.rope_theta)
-        attn = ops.batched_paged_decode_attention(
-            q, kp, vp, page_table, seq_lens, k_new, v_new,
-            max_pages=max_pages, logit_softcap=cfg.logit_softcap)
-        kp = kp.at[phys, :, offset].set(k_new.astype(kp.dtype))
-        vp = vp.at[phys, :, offset].set(v_new.astype(vp.dtype))
-        h = h + jnp.einsum("bhk,hkd->bd", attn, lp["attn"]["wo"])
-        mlp_in = nn.apply_norm(cfg, lp["ln2"], h)
-        if cfg.family == "moe":
-            h = h + nn.moe_block_decode(cfg, lp["moe"], mlp_in)
-        else:
-            h = h + nn.mlp_block(cfg, lp["mlp"], mlp_in)
+        with jax.named_scope(spans.QKV):
+            attn_in = nn.apply_norm(cfg, lp["ln1"], h)
+            q = jnp.einsum("bd,dhk->bhk", attn_in, lp["attn"]["wq"])
+            k_new = jnp.einsum("bd,dhk->bhk", attn_in, lp["attn"]["wk"])
+            v_new = jnp.einsum("bd,dhk->bhk", attn_in, lp["attn"]["wv"])
+            if cfg.pos == "rope":
+                q = nn.apply_rope(q, seq_lens, cfg.rope_theta)
+                k_new = nn.apply_rope(k_new, seq_lens, cfg.rope_theta)
+        with jax.named_scope(spans.ATTENTION):
+            attn = ops.batched_paged_decode_attention(
+                q, kp, vp, page_table, seq_lens, k_new, v_new,
+                max_pages=max_pages, logit_softcap=cfg.logit_softcap)
+        with jax.named_scope(spans.KV_WRITE):
+            kp = kp.at[phys, :, offset].set(k_new.astype(kp.dtype))
+            vp = vp.at[phys, :, offset].set(v_new.astype(vp.dtype))
+        with jax.named_scope(spans.OUT_PROJ_MLP):
+            h = h + jnp.einsum("bhk,hkd->bd", attn, lp["attn"]["wo"])
+            mlp_in = nn.apply_norm(cfg, lp["ln2"], h)
+            if cfg.family == "moe":
+                h = h + nn.moe_block_decode(cfg, lp["moe"], mlp_in)
+            else:
+                h = h + nn.mlp_block(cfg, lp["mlp"], mlp_in)
         return h, (kp, vp)
 
     h, (ks, vs) = nn.scan_layers(
         scan_body, x, (params["layers"], pool["k"], pool["v"]))
-    h = nn.apply_norm(cfg, params["final_norm"], h)
-    head = params["embed"] if cfg.tie_embeddings else params["lm_head"]
-    logits = nn.unembed(cfg, head, h)
+    with jax.named_scope(spans.HEAD):
+        h = nn.apply_norm(cfg, params["final_norm"], h)
+        head = params["embed"] if cfg.tie_embeddings else params["lm_head"]
+        logits = nn.unembed(cfg, head, h)
     return logits, {"k": ks, "v": vs}
 
 
@@ -281,30 +287,36 @@ def prefill_chunk_paged(cfg: ModelConfig, params: Dict, pool: Dict,
     def scan_body(h, xs):
         lp, kp, vp = xs                                # [n_pages, Hk, page, hd]
         h = constrain(h, "batch", None, "residual")
-        attn_in = nn.apply_norm(cfg, lp["ln1"], h)
-        q, k, v = nn.qkv_project(lp["attn"], attn_in)  # [B, C, H/Hk, hd]
-        if cfg.pos == "rope":
-            q = nn.apply_rope(q, positions, cfg.rope_theta)
-            k = nn.apply_rope(k, positions, cfg.rope_theta)
-        kp = kp.at[phys, :, offset].set(k.astype(kp.dtype))
-        vp = vp.at[phys, :, offset].set(v.astype(vp.dtype))
-        attn = ops.chunked_prefill_attention(
-            q, kp, vp, page_table, q_offset, kv_lens,
-            logit_softcap=cfg.logit_softcap)
-        h = h + jnp.einsum("blhk,hkd->bld", attn, lp["attn"]["wo"])
-        mlp_in = nn.apply_norm(cfg, lp["ln2"], h)
-        if cfg.family == "moe":
-            out, _ = nn.moe_block(cfg, lp["moe"], mlp_in)
-            h = h + out
-        else:
-            h = h + nn.mlp_block(cfg, lp["mlp"], mlp_in)
+        with jax.named_scope(spans.QKV):
+            attn_in = nn.apply_norm(cfg, lp["ln1"], h)
+            # [B, C, H/Hk, hd]
+            q, k, v = nn.qkv_project(lp["attn"], attn_in)
+            if cfg.pos == "rope":
+                q = nn.apply_rope(q, positions, cfg.rope_theta)
+                k = nn.apply_rope(k, positions, cfg.rope_theta)
+        with jax.named_scope(spans.KV_WRITE):
+            kp = kp.at[phys, :, offset].set(k.astype(kp.dtype))
+            vp = vp.at[phys, :, offset].set(v.astype(vp.dtype))
+        with jax.named_scope(spans.ATTENTION):
+            attn = ops.chunked_prefill_attention(
+                q, kp, vp, page_table, q_offset, kv_lens,
+                logit_softcap=cfg.logit_softcap)
+        with jax.named_scope(spans.OUT_PROJ_MLP):
+            h = h + jnp.einsum("blhk,hkd->bld", attn, lp["attn"]["wo"])
+            mlp_in = nn.apply_norm(cfg, lp["ln2"], h)
+            if cfg.family == "moe":
+                out, _ = nn.moe_block(cfg, lp["moe"], mlp_in)
+                h = h + out
+            else:
+                h = h + nn.mlp_block(cfg, lp["mlp"], mlp_in)
         return h, (kp, vp)
 
     h, (ks, vs) = nn.scan_layers(
         scan_body, x, (params["layers"], pool["k"], pool["v"]))
-    h = nn.apply_norm(cfg, params["final_norm"], h[:, -1])
-    head = params["embed"] if cfg.tie_embeddings else params["lm_head"]
-    logits = nn.unembed(cfg, head, h)
+    with jax.named_scope(spans.HEAD):
+        h = nn.apply_norm(cfg, params["final_norm"], h[:, -1])
+        head = params["embed"] if cfg.tie_embeddings else params["lm_head"]
+        logits = nn.unembed(cfg, head, h)
     return logits, {"k": ks, "v": vs}
 
 

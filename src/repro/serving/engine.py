@@ -81,6 +81,7 @@ from ..models.registry import get_api
 from ..models.steps import sample_logits
 from ..obs import events as tr
 from ..obs import resolve_recorder
+from ..obs import spans as sp
 from .kv_cache import PagedPool, PagedSeqLedger, prefix_page_key
 from .metrics import RunMetrics, summarize_run
 
@@ -419,26 +420,30 @@ class ServingEngine:
         vlm-paged path): the slot's last prompt chunk landed, execute
         the whole bucket at once and emit the first token."""
         st = self.slots[slot]
-        self._rng, sub = jax.random.split(self._rng)
-        if self.ecfg.paged:
-            from ..models import transformer
-            from .kv_cache import write_prefill_pages
-            logits, k_lv, v_lv = transformer.prefill_kv(
-                self.cfg, self.params, st.batch["tokens"],
-                patches=st.batch.get("patches"))
-            cached = self.ledger.cached_tokens(slot)
-            pages = self.ledger.table(slot)[cached // self.ecfg.page_size:]
-            self.pool = write_prefill_pages(
-                self.pool, (k_lv[:, 0], v_lv[:, 0]), pages, st.bucket,
-                start_token=cached)
-            if self.prefix_tree is not None:
-                self.ledger.donate(slot, now)
-            tok = sample_logits(logits, sub, self.ecfg.temperature)
-        else:
-            tok, cache_1 = self._prefill_fn_for(st.bucket)(
-                self.params, st.batch, sub)
-            self._insert_cache(slot, cache_1)
-        self._emit_first_token(slot, int(tok[0]), now)
+        with sp.span(sp.ENGINE_PREFILL):
+            self._rng, sub = jax.random.split(self._rng)
+            if self.ecfg.paged:
+                from ..models import transformer
+                from .kv_cache import write_prefill_pages
+                logits, k_lv, v_lv = transformer.prefill_kv(
+                    self.cfg, self.params, st.batch["tokens"],
+                    patches=st.batch.get("patches"))
+                cached = self.ledger.cached_tokens(slot)
+                pages = self.ledger.table(slot)[
+                    cached // self.ecfg.page_size:]
+                self.pool = write_prefill_pages(
+                    self.pool, (k_lv[:, 0], v_lv[:, 0]), pages, st.bucket,
+                    start_token=cached)
+                if self.prefix_tree is not None:
+                    self.ledger.donate(slot, now)
+                tok = sample_logits(logits, sub, self.ecfg.temperature)
+            else:
+                tok, cache_1 = self._prefill_fn_for(st.bucket)(
+                    self.params, st.batch, sub)
+                self._insert_cache(slot, cache_1)
+            with sp.span(sp.FIRST_TOKEN_SYNC):
+                tok = int(tok[0])
+            self._emit_first_token(slot, tok, now)
 
     def _advance_prefill(self, slot: int, take: int, now: float) -> None:
         """Execute this iteration's device chunk for a paged slot
@@ -461,24 +466,27 @@ class ServingEngine:
                 start = end - 1
         if end <= start:
             return                       # budget exhausted, mid-prompt
-        chunk_len = end - start
-        pt = jnp.asarray(self.ledger.table_array(
-            [slot], self.alloc.pages_per_seq))
-        logits, new_pool = self._chunk_fn_for(chunk_len)(
-            self.params, {"k": self.pool.k, "v": self.pool.v},
-            st.batch["tokens"][:, start:end], pt,
-            jnp.asarray([start], jnp.int32))
-        self.pool = PagedPool(k=new_pool["k"], v=new_pool["v"],
-                              page_size=self.ecfg.page_size)
-        self.n_prefill_launches += 1
-        self.prefill_chunk_log.append((slot, chunk_len))
-        st.prefill_pos = end
-        if final:
-            self._rng, sub = jax.random.split(self._rng)
-            if self.prefix_tree is not None:
-                self.ledger.donate(slot, now)
-            tok = sample_logits(logits, sub, self.ecfg.temperature)
-            self._emit_first_token(slot, int(tok[0]), now)
+        with sp.span(sp.ENGINE_PREFILL):
+            chunk_len = end - start
+            pt = jnp.asarray(self.ledger.table_array(
+                [slot], self.alloc.pages_per_seq))
+            logits, new_pool = self._chunk_fn_for(chunk_len)(
+                self.params, {"k": self.pool.k, "v": self.pool.v},
+                st.batch["tokens"][:, start:end], pt,
+                jnp.asarray([start], jnp.int32))
+            self.pool = PagedPool(k=new_pool["k"], v=new_pool["v"],
+                                  page_size=self.ecfg.page_size)
+            self.n_prefill_launches += 1
+            self.prefill_chunk_log.append((slot, chunk_len))
+            st.prefill_pos = end
+            if final:
+                self._rng, sub = jax.random.split(self._rng)
+                if self.prefix_tree is not None:
+                    self.ledger.donate(slot, now)
+                tok = sample_logits(logits, sub, self.ecfg.temperature)
+                with sp.span(sp.FIRST_TOKEN_SYNC):
+                    tok = int(tok[0])
+                self._emit_first_token(slot, tok, now)
 
     def _emit_first_token(self, slot: int, tok: int, now: float) -> None:
         """Prefill-completion bookkeeping shared by the single-shot and
@@ -602,7 +610,8 @@ class ServingEngine:
         st = self.slots[slot]
         req = st.req
         req.exec_end = now
-        self.sched.complete(req, st.generated, now)
+        with sp.span(sp.SCHED_COMPLETE):
+            self.sched.complete(req, st.generated, now)
         if self.trace.enabled:
             self.trace.emit(now, tr.COMPLETE, req_id=req.req_id,
                             rid=self.trace_rid, tenant=req.tenant.label,
@@ -628,33 +637,41 @@ class ServingEngine:
         of completions this step. Per-iteration admission honours the
         scheduler's ``max_new_per_step`` knob — the same slot-granular
         contract the discrete-event step engine uses
-        (``DriftScheduler.dispatch_step``)."""
+        (``DriftScheduler.dispatch_step``). Each phase is a host span of
+        ``obs/spans.py``."""
+        with sp.span(sp.ENGINE_STEP):
+            return self._step(now)
+
+    def _step(self, now: float) -> int:
         # admission (iteration boundary, interleaving with decode)
-        joined = 0
-        cap = self.sched.max_new_per_step
-        pages_per_seq = (self.alloc.pages_per_seq if self.ecfg.paged
-                         else 0)
-        for slot in self.free_slots():
-            if self.sched.queue_depth() == 0:
-                break
-            if cap is not None and joined >= cap:
-                break
-            if self.ecfg.paged and self.prefix_tree is not None \
-                    and not self.ledger.can_admit(
-                        pages_per_seq * self.ecfg.page_size):
-                # conservative page guard: admission waits for
-                # retirements/evictions to free room (only reachable
-                # with a prefix cache — the plain pool is sized exactly)
-                break
-            req = self.sched.dispatch(now)
-            if req is None:
-                break
-            payload = self._pending_injections.pop(req.req_id, None)
-            if payload is not None:
-                self._admit_prefilled(req, slot, payload, now)
-            else:
-                self._admit(req, slot, now)
-            joined += 1
+        with sp.span(sp.ENGINE_ADMIT):
+            joined = 0
+            cap = self.sched.max_new_per_step
+            pages_per_seq = (self.alloc.pages_per_seq if self.ecfg.paged
+                             else 0)
+            for slot in self.free_slots():
+                if self.sched.queue_depth() == 0:
+                    break
+                if cap is not None and joined >= cap:
+                    break
+                if self.ecfg.paged and self.prefix_tree is not None \
+                        and not self.ledger.can_admit(
+                            pages_per_seq * self.ecfg.page_size):
+                    # conservative page guard: admission waits for
+                    # retirements/evictions to free room (only reachable
+                    # with a prefix cache — the plain pool is sized
+                    # exactly)
+                    break
+                with sp.span(sp.SCHED_DISPATCH):
+                    req = self.sched.dispatch(now)
+                if req is None:
+                    break
+                payload = self._pending_injections.pop(req.req_id, None)
+                if payload is not None:
+                    self._admit_prefilled(req, slot, payload, now)
+                else:
+                    self._admit(req, slot, now)
+                joined += 1
 
         # chunked prefill: apportion the per-step budget in join order;
         # a slot's prefill-completing iteration also emits its first
@@ -690,83 +707,94 @@ class ServingEngine:
                 self.busy_steps += 1
             return 0
 
-        tokens = np.zeros((self.ecfg.n_slots,), np.int32)
-        for i in decoding:
-            tokens[i] = self.slots[i].last_token
-        self._rng, sub = jax.random.split(self._rng)
-        if self.ecfg.paged:
-            sids = [i if (self.slots[i].req is not None
-                          and not self.slots[i].pending_prefill) else None
-                    for i in range(self.ecfg.n_slots)]
-            lens = self.ledger.lens_array(sids)  # tokens before this step
+        with sp.span(sp.DECODE_PREP):
+            tokens = np.zeros((self.ecfg.n_slots,), np.int32)
             for i in decoding:
-                # grow the table first: this step writes the token's KV
-                # at position lens[i], which opens a new page whenever
-                # lens[i] is page-aligned
-                _, cows = self.ledger.extend(i, 1)
-                for old, new in cows:
-                    # copy-on-write boundary: the ledger handed this
-                    # slot a private copy of a shared page — mirror it
-                    # device-side before this step's write lands there.
-                    # Unreachable with full-page prefix keys (suffix
-                    # pages are always private) but wired for the
-                    # partial-page layouts cow_extend exists for.
-                    self.pool = PagedPool(
-                        k=self.pool.k.at[:, new].set(self.pool.k[:, old]),
-                        v=self.pool.v.at[:, new].set(self.pool.v[:, old]),
-                        page_size=self.ecfg.page_size)
-            pt = self.ledger.table_array(sids, self.alloc.pages_per_seq)
-            scratch = self.pool.n_pages - 1      # never allocated: inactive
-            for i, sid in enumerate(sids):       # slots write there
-                if sid is None:
-                    pt[i, :] = scratch
-            # static page-grid trim for the batched kernel: next power
-            # of two above the deepest live sequence (bounded set of
-            # jit variants), clamped to the table width
-            needed = max(1, -(-int(lens.max()) // self.ecfg.page_size))
-            max_pages = 1
-            while max_pages < needed:
-                max_pages *= 2
-            max_pages = min(max_pages, self.alloc.pages_per_seq)
-            toks, new_pool = self._decode_paged(
-                self.params, {"k": self.pool.k, "v": self.pool.v},
-                jnp.asarray(tokens), jnp.asarray(pt),
-                jnp.asarray(lens), sub, max_pages=max_pages)
-            self.pool = PagedPool(k=new_pool["k"], v=new_pool["v"],
-                                  page_size=self.ecfg.page_size)
-        else:
-            pos = np.asarray(self.cache["lens"])     # per-slot depth
-            toks, self.cache = self._decode(
-                self.params, self.cache, jnp.asarray(tokens),
-                jnp.asarray(pos, np.int32), sub)
-        toks = np.asarray(toks)
-
-        done = 0
-        tron = self.trace.enabled
-        for i in decoding:
-            st = self.slots[i]
-            st.generated += 1
-            st.last_token = int(toks[i])
-            if tron:
-                self.trace.emit(now, tr.DECODE_STEP,
-                                req_id=st.req.req_id, rid=self.trace_rid,
-                                n=st.generated, token=st.last_token)
-            if st.generated >= st.target:       # oracle EOS
-                self._retire(i, now)
-                done += 1
-        self.step_count += 1
-        self.busy_steps += 1
-        if tron:
-            self.trace.emit(now, tr.GAUGE, rid=self.trace_rid,
-                            name="queue_depth",
-                            value=self.sched.queue_depth())
-            self.trace.emit(now, tr.GAUGE, rid=self.trace_rid,
-                            name="active_slots",
-                            value=len(self.active_slots()))
+                tokens[i] = self.slots[i].last_token
+            self._rng, sub = jax.random.split(self._rng)
             if self.ecfg.paged:
+                sids = [i if (self.slots[i].req is not None
+                              and not self.slots[i].pending_prefill)
+                        else None for i in range(self.ecfg.n_slots)]
+                lens = self.ledger.lens_array(sids)  # tokens before step
+                for i in decoding:
+                    # grow the table first: this step writes the token's
+                    # KV at position lens[i], which opens a new page
+                    # whenever lens[i] is page-aligned
+                    _, cows = self.ledger.extend(i, 1)
+                    for old, new in cows:
+                        # copy-on-write boundary: the ledger handed this
+                        # slot a private copy of a shared page — mirror
+                        # it device-side before this step's write lands
+                        # there. Unreachable with full-page prefix keys
+                        # (suffix pages are always private) but wired
+                        # for the partial-page layouts cow_extend exists
+                        # for.
+                        self.pool = PagedPool(
+                            k=self.pool.k.at[:, new].set(
+                                self.pool.k[:, old]),
+                            v=self.pool.v.at[:, new].set(
+                                self.pool.v[:, old]),
+                            page_size=self.ecfg.page_size)
+                pt = self.ledger.table_array(sids, self.alloc.pages_per_seq)
+                scratch = self.pool.n_pages - 1  # never allocated: inactive
+                for i, sid in enumerate(sids):   # slots write there
+                    if sid is None:
+                        pt[i, :] = scratch
+                # static page-grid trim for the batched kernel: next
+                # power of two above the deepest live sequence (bounded
+                # set of jit variants), clamped to the table width
+                needed = max(1, -(-int(lens.max()) // self.ecfg.page_size))
+                max_pages = 1
+                while max_pages < needed:
+                    max_pages *= 2
+                max_pages = min(max_pages, self.alloc.pages_per_seq)
+                inputs = (jnp.asarray(tokens), jnp.asarray(pt),
+                          jnp.asarray(lens))
+            else:
+                pos = np.asarray(self.cache["lens"])     # per-slot depth
+                inputs = (jnp.asarray(tokens), jnp.asarray(pos, np.int32))
+        with sp.span(sp.DECODE_LAUNCH):
+            if self.ecfg.paged:
+                toks, new_pool = self._decode_paged(
+                    self.params, {"k": self.pool.k, "v": self.pool.v},
+                    *inputs, sub, max_pages=max_pages)
+                self.pool = PagedPool(k=new_pool["k"], v=new_pool["v"],
+                                      page_size=self.ecfg.page_size)
+            else:
+                toks, self.cache = self._decode(
+                    self.params, self.cache, *inputs, sub)
+        with sp.span(sp.DECODE_SYNC):
+            toks = np.asarray(toks)
+
+        with sp.span(sp.ENGINE_RETIRE):
+            done = 0
+            tron = self.trace.enabled
+            for i in decoding:
+                st = self.slots[i]
+                st.generated += 1
+                st.last_token = int(toks[i])
+                if tron:
+                    self.trace.emit(now, tr.DECODE_STEP,
+                                    req_id=st.req.req_id,
+                                    rid=self.trace_rid,
+                                    n=st.generated, token=st.last_token)
+                if st.generated >= st.target:       # oracle EOS
+                    self._retire(i, now)
+                    done += 1
+            self.step_count += 1
+            self.busy_steps += 1
+            if tron:
                 self.trace.emit(now, tr.GAUGE, rid=self.trace_rid,
-                                name="kv_free_pages",
-                                value=self.alloc.free_pages)
+                                name="queue_depth",
+                                value=self.sched.queue_depth())
+                self.trace.emit(now, tr.GAUGE, rid=self.trace_rid,
+                                name="active_slots",
+                                value=len(self.active_slots()))
+                if self.ecfg.paged:
+                    self.trace.emit(now, tr.GAUGE, rid=self.trace_rid,
+                                    name="kv_free_pages",
+                                    value=self.alloc.free_pages)
         return done
 
     def run_until_drained(self, *, max_steps: int = 100_000,

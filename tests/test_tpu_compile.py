@@ -67,13 +67,20 @@ def no_persistent_cache():
     compilation_cache.reset_cache()
 
 
-def _compile(fn, sharding, *shapes):
-    """Compile ``fn`` for the described chip; return its HLO text."""
+def _compile(fn, sharding, *shapes, kernel=None):
+    """Compile ``fn`` for the described chip; return its HLO text. With
+    ``kernel``, every Pallas call in it must carry that name (the
+    profiler names a kernel's operation by it)."""
     args = [jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
             for shape, dtype in shapes]
     compiled = jax.jit(fn).lower(*args).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text, "no Pallas kernel in the program"
+    if kernel is not None:
+        calls = [line.strip() for line in text.splitlines()
+                 if 'custom_call_target="tpu_custom_call"' in line]
+        assert all(c.startswith(f"%{kernel}") or
+                   c.startswith(f"ROOT %{kernel}") for c in calls), calls
     return text
 
 
@@ -91,7 +98,8 @@ def test_paged_decode_compiles(model, one_chip, no_persistent_cache):
     _compile(lambda q, k, v, pt, sl: paged_decode_attention(q, k, v, pt, sl),
              one_chip,
              ((N_SLOTS, H, D), jnp.bfloat16), _pool(Hk, D), _pool(Hk, D),
-             _table(), ((N_SLOTS,), jnp.int32))
+             _table(), ((N_SLOTS,), jnp.int32),
+             kernel="paged_decode_attention")
 
 
 @pytest.mark.parametrize("max_pages", [None, 8])
@@ -107,7 +115,8 @@ def test_batched_paged_decode_compiles(model, max_pages, one_chip,
              ((N_SLOTS, H, D), jnp.bfloat16), _pool(Hk, D), _pool(Hk, D),
              _table(), ((N_SLOTS,), jnp.int32),
              ((N_SLOTS, Hk, D), jnp.bfloat16),
-             ((N_SLOTS, Hk, D), jnp.bfloat16))
+             ((N_SLOTS, Hk, D), jnp.bfloat16),
+             kernel="batched_paged_decode_attention")
 
 
 # chunk lengths the engine launches: a full budget slab, and a ragged
@@ -122,7 +131,7 @@ def test_chunked_prefill_compiles(model, chunk, one_chip,
              one_chip,
              ((1, chunk, H, D), jnp.bfloat16), _pool(Hk, D), _pool(Hk, D),
              ((1, PAGES_PER_SEQ), jnp.int32), ((1,), jnp.int32),
-             ((1,), jnp.int32))
+             ((1,), jnp.int32), kernel="chunked_prefill_attention")
 
 
 @pytest.mark.parametrize("L", [512, 200])
