@@ -14,7 +14,12 @@ from repro.kernels import (
     ssd_scan,
 )
 from repro.kernels import ref
-from repro.kernels.paged_attention import safe_page_index
+from repro.kernels.paged_attention import (
+    BLOCK_BYTES,
+    block_schedule,
+    pages_per_block,
+    safe_page_index,
+)
 
 
 def _rand(key, shape, dtype):
@@ -173,20 +178,41 @@ def _page_scene(key, B, Hk, D, page, pps, dtype, extra=3):
     return k_pages, v_pages, page_table
 
 
+# (H, Hk, D, page, pages_per_seq, seq_lens): "mixed" is a small pool;
+# the others put lengths at the kernel's block edges (0, 1, one block of
+# ``ppb * page`` tokens, one block plus one, a full table), at smollm-135m's
+# heads and at h2o-danube-1.8b's (whose float32 pages make the block bytes
+# bind); 18 pages per sequence leave a ragged last block in each
+_BLOCK_EDGES = "block-edges"
+_BATCHED_SCENES = {
+    "mixed": (4, 2, 32, 8, 6, [5, 17, 29]),
+    "edges": (4, 2, 32, 8, 6, _BLOCK_EDGES),
+    "smollm-heads": (9, 3, 64, 16, 18, _BLOCK_EDGES),
+    "danube-heads": (32, 8, 80, 16, 18, _BLOCK_EDGES),
+}
+
+
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize("max_pages", [None, "trim"])
-def test_batched_paged_decode_vs_per_sequence(dtype, max_pages):
+@pytest.mark.parametrize("scene", list(_BATCHED_SCENES))
+def test_batched_paged_decode_vs_per_sequence(scene, dtype, max_pages):
     """The batched kernel == scatter-then-per-sequence decode calls."""
     key = jax.random.PRNGKey(10)
     ks = jax.random.split(key, 4)
-    B, H, Hk, D, page, pps = 3, 4, 2, 32, 8, 6
+    H, Hk, D, page, pps, lens = _BATCHED_SCENES[scene]
+    if lens == _BLOCK_EDGES:
+        block = page * pages_per_block(
+            page, Hk * page * D * jnp.dtype(dtype).itemsize, pps)
+        assert pps * page > block + 1 and pps % (block // page)  # ragged
+        lens = [0, 1, block, block + 1, pps * page - 1]
+    B = len(lens)
     k_pages, v_pages, page_table = _page_scene(ks[0], B, Hk, D, page, pps,
                                                dtype)
     q = _rand(ks[1], (B, H, D), dtype)
     k_new = _rand(ks[2], (B, Hk, D), dtype)
     v_new = _rand(ks[3], (B, Hk, D), dtype)
-    seq_lens = jnp.array([5, 17, 29], jnp.int32)
-    mp = None if max_pages is None else max(1, -(-29 // page))
+    seq_lens = jnp.array(lens, jnp.int32)
+    mp = None if max_pages is None else max(1, -(-max(lens) // page))
     got = batched_paged_decode_attention(
         q, k_pages, v_pages, page_table, seq_lens, k_new, v_new,
         max_pages=mp, interpret=True)
@@ -226,6 +252,48 @@ def test_batched_paged_decode_softcap():
         logit_softcap=30.0)
     np.testing.assert_allclose(np.asarray(got), np.asarray(expect),
                                atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("page,page_bytes,n_page_steps,ppb", [
+    (16, 3 * 16 * 64 * 2, 64, 16),        # smollm-135m's pool: the keys bind
+    (16, 8 * 16 * 80 * 2, 144, 16),       # h2o-danube-1.8b's
+    (16, 8 * 16 * 256 * 2, 64, 8),        # 64 KiB pages: the bytes bind
+    (8, 2 * 8 * 32 * 4, 6, 4),            # the page grid binds
+    (16, 3 * 16 * 64 * 2, 1, 1),
+    (16, BLOCK_BYTES + 1, 64, 1),         # one page over the budget
+])
+def test_pages_per_block(page, page_bytes, n_page_steps, ppb):
+    assert pages_per_block(page, page_bytes, n_page_steps) == ppb
+
+
+def test_block_schedule_steps_live_blocks_and_holds_dead_slots():
+    """One step per block holding keys (one for an empty sequence); a slot
+    past the live length repeats the sequence's last live page (an empty
+    sequence's first entry, its scratch page), so no step reads a table
+    entry past the live length."""
+    page, ppb = 8, 4
+    live = [[3, 9, 1, 4, 7], [5], [20, 21, 22, 23, 24, 25, 26, 27, 28]]
+    garbage = 777_777
+    page_table = jnp.array([row + [garbage] * (12 - len(row))
+                            for row in live], jnp.int32)
+    seq_lens = jnp.array([5 * page - 3, 0, 9 * page], jnp.int32)
+    n_steps, step_seq, step_block, step_pages = block_schedule(
+        page_table, seq_lens, page_size=page, n_page_steps=12, ppb=ppb)
+    assert int(n_steps) == 2 + 1 + 3
+    assert step_seq.shape == (3 * 3,)                  # B * ceil(12 / ppb)
+    assert list(np.asarray(step_seq[:6])) == [0, 0, 1, 2, 2, 2]
+    assert list(np.asarray(step_block[:6])) == [0, 1, 0, 0, 1, 2]
+    slots = np.asarray(step_pages).reshape(-1, ppb)[:6].tolist()
+    assert slots == [
+        [3, 9, 1, 4],
+        [7, 7, 7, 7],           # slots 1-3 past the end: the last live page
+        [5, 5, 5, 5],           # the empty sequence: its scratch page
+        [20, 21, 22, 23],
+        [24, 25, 26, 27],
+        [28, 28, 28, 28],
+    ]
+    # unused steps past n_steps too
+    assert garbage not in np.asarray(step_pages)
 
 
 # ---------------------------------------------------------------------------

@@ -6,7 +6,10 @@ VMEM rules. These tests hand the TPU compiler installed with JAX a v5e
 chip that is described, not attached, and compile each kernel at the
 head shapes of the registered configurations the engine serves:
 smollm-135m (H=9, Hk=3, D=64) for all five, minitron-4b (H=24, Hk=8,
-D=128) for the two paged kernels, mamba2-2.7b for the SSD scan. Nothing
+D=128) for the two paged kernels, h2o-danube-1.8b (H=32, Hk=8, D=80) for
+the batched decode kernel, mamba2-2.7b for the SSD scan. The batched
+decode kernel is also compiled at each benchmark cell's own slots and
+pool, where its double-buffered pages must fit the scoped VMEM. Nothing
 runs, so these say nothing about results or times.
 
 The topology is described inside a module-scoped fixture: only one
@@ -32,6 +35,7 @@ from repro.kernels import (
 # (name, H, Hk, D) of the configurations whose head shapes are compiled
 SMOLLM = ("smollm-135m", 9, 3, 64)
 MINITRON = ("minitron-4b", 24, 8, 128)
+DANUBE = ("h2o-danube-1.8b", 32, 8, 80)
 
 # engine-sized pool: 16 slots x 1024 tokens in 16-token pages
 N_SLOTS, PAGE, PAGES_PER_SEQ = 16, 16, 64
@@ -102,21 +106,44 @@ def test_paged_decode_compiles(model, one_chip, no_persistent_cache):
              kernel="paged_decode_attention")
 
 
-@pytest.mark.parametrize("max_pages", [None, 8])
-@pytest.mark.parametrize("model", [SMOLLM, MINITRON], ids=lambda m: m[0])
-def test_batched_paged_decode_compiles(model, max_pages, one_chip,
-                                       no_persistent_cache):
+def _compile_batched_decode(model, sharding, n_slots, pages_per_seq,
+                            n_pool_pages, max_pages):
     _, H, Hk, D = model
 
     def fn(q, k, v, pt, sl, kn, vn):
         return batched_paged_decode_attention(q, k, v, pt, sl, kn, vn,
                                               max_pages=max_pages)
-    _compile(fn, one_chip,
-             ((N_SLOTS, H, D), jnp.bfloat16), _pool(Hk, D), _pool(Hk, D),
-             _table(), ((N_SLOTS,), jnp.int32),
-             ((N_SLOTS, Hk, D), jnp.bfloat16),
-             ((N_SLOTS, Hk, D), jnp.bfloat16),
+    pool = ((n_pool_pages, Hk, PAGE, D), jnp.bfloat16)
+    _compile(fn, sharding,
+             ((n_slots, H, D), jnp.bfloat16), pool, pool,
+             ((n_slots, pages_per_seq), jnp.int32), ((n_slots,), jnp.int32),
+             ((n_slots, Hk, D), jnp.bfloat16),
+             ((n_slots, Hk, D), jnp.bfloat16),
              kernel="batched_paged_decode_attention")
+
+
+# max_pages 12 leaves a ragged last block of pages at every head shape
+@pytest.mark.parametrize("max_pages", [None, 8, 12])
+@pytest.mark.parametrize("model", [SMOLLM, MINITRON, DANUBE],
+                         ids=lambda m: m[0])
+def test_batched_paged_decode_compiles(model, max_pages, one_chip,
+                                       no_persistent_cache):
+    _compile_batched_decode(model, one_chip, N_SLOTS, PAGES_PER_SEQ,
+                            N_POOL_PAGES, max_pages)
+
+
+# the benchmark cells' engines: slots, pages per sequence, pool pages
+# (every slot's table, the prefix cache's pages, the scratch page)
+@pytest.mark.parametrize("model,n_slots,pages_per_seq,n_pool_pages", [
+    (SMOLLM, 128, 64, 128 * 64 + 1),
+    (DANUBE, 64, 64, 64 * 64 + 1),
+    (DANUBE, 23, 144, 23 * 144 + 768 + 1),
+], ids=["smollm135m-chat", "danube1.8b-chat", "danube1.8b-rag"])
+def test_batched_paged_decode_compiles_at_cell_size(
+        model, n_slots, pages_per_seq, n_pool_pages, one_chip,
+        no_persistent_cache):
+    _compile_batched_decode(model, one_chip, n_slots, pages_per_seq,
+                            n_pool_pages, pages_per_seq)
 
 
 # chunk lengths the engine launches: a full budget slab, and a ragged
