@@ -9,9 +9,10 @@ same sample of finished requests:
 * ``program``: the widest gap by which a served token's float32
   reference logit lies below the reference's best (what ``run.py``
   holds to the limit);
-* ``control``: the same gap for the token that the float8 control (the
-  reference with every matmul operand in float8) puts first at each
-  position of the same prompts and served tokens.
+* ``control``: the same gap for the token that the family's control
+  (the dense family's: the reference with every matmul operand in
+  float8) puts first at each position of the same prompts and served
+  tokens.
 
 The limit lies above the program's readings and below the control's.
 ``--smoke`` runs the configuration's small CPU widths with the kernels'
@@ -38,13 +39,14 @@ def readings(root: Path, workload: str, seed: int, seconds: float, *,
     out = {}
     plain_check = harness.check
 
-    def check(conf, mix, params, finished, seed, prefix_cache, max_len, log):
-        checks, ok = plain_check(conf, mix, params, finished, seed,
+    def check(fam, conf, mix, params, finished, seed, prefix_cache, max_len,
+              log):
+        checks, ok = plain_check(fam, conf, mix, params, finished, seed,
                                  prefix_cache, max_len, log)
-        vocab = harness.bmodel.shapes(conf)["V"]
+        vocab = fam.shapes(conf)["V"]
         picked = harness.sample(finished, seed, int(mix["check"]["sample"]))
         out["control"] = max(
-            float(np.max(harness.gaps_of(conf, params, r, vocab,
+            float(np.max(harness.gaps_of(fam, conf, params, r, vocab,
                                          prefix_cache, max_len,
                                          control=True)))
             for r in picked)

@@ -1,23 +1,34 @@
-"""Weights, the plain reference and its lower-precision control.
+"""The dense decoder family: Llama and Mistral.
 
-The configuration file (``bench/configs/<config>.json``) holds the
-model's published sizes under their published keys. From it this
-module makes:
+A configuration file (``bench/configs/<config>.json``) names its family
+with ``"family"``; without the key it is this one. A family module
+gives the harness everything it knows of the model:
 
-* the weights, on the device in one jitted call from the seed, in the
-  type they are served in (bfloat16) and in the layout the serving
-  engine reads: per-layer leaves stacked on a leading layer axis,
-  attention weights head-split (``wq [L, d, H, hd]``, ``wo [L, H, hd,
-  d]``), the gated MLP as ``w1`` (gate), ``w3`` (up) and ``w2`` (down),
-  and each RMSNorm gain stored as its offset from 1;
-* the plain reference: the decoder's forward pass in float32
-  ``jax.numpy`` at ``highest`` matmul precision, written from the
-  published Llama/Mistral description (RMSNorm, rotate-half RoPE,
-  grouped-query causal attention, SwiGLU, tied or untied head). It
-  imports nothing of the program and runs one layer's weights at a time;
-* the control: the same forward with every matmul operand rounded to
-  float8 (e4m3, one scale per tensor), the precision below the
-  configuration's bfloat16.
+* ``shapes(conf)``: the sizes the counting functions and metric readers
+  read (``L``, ``d``, ``H``, ``Hk``, ``D``, ``V``, ``windows``: each
+  layer's attention window or ``None``, and ``mm_flops_per_token``: the
+  projections and feed-forward work of one token over all layers);
+* ``model_config(conf, s)``: the ``repro.models.config.ModelConfig``
+  the serving engine serves;
+* ``init_params(conf, seed, device)``: the weights, on the device in one
+  jitted call from the seed, in the type they are served in (bfloat16)
+  and in the layout the engine reads: per-layer leaves stacked on a
+  leading layer axis, attention weights head-split (``wq [L, d, H,
+  hd]``, ``wo [L, H, hd, d]``), the gated MLP as ``w1`` (gate), ``w3``
+  (up) and ``w2`` (down), and each RMSNorm gain stored as its offset
+  from 1;
+* ``logits(conf, params, tokens, *, low=False, pad_to=0)``: the plain
+  reference, the decoder's forward pass in float32 ``jax.numpy`` at
+  ``highest`` matmul precision, written from the published
+  Llama/Mistral description (RMSNorm, rotate-half RoPE, grouped-query
+  causal attention, Mistral's sliding window, SwiGLU, tied or untied
+  head). It imports nothing of the program and runs one layer's weights
+  at a time. With ``low``, the control: the same forward with every
+  matmul operand rounded to float8 (e4m3, one scale per tensor), the
+  precision below the configuration's bfloat16.
+
+Another family may build on this one with ``from families import
+dense``.
 """
 
 from __future__ import annotations
@@ -30,13 +41,46 @@ import jax.numpy as jnp
 import numpy as np
 
 
+def windows(conf: dict) -> list:
+    """Each layer's attention window, or ``None`` for full attention:
+    ``sliding_window`` in every layer (Mistral), or in the layers that
+    ``layer_types`` marks ``sliding_attention``."""
+    L, w = conf["num_hidden_layers"], conf.get("sliding_window")
+    types = conf.get("layer_types") or ["sliding_attention"] * L
+    if len(types) != L:
+        raise ValueError(f"layer_types lists {len(types)} layers, "
+                         f"num_hidden_layers is {L}")
+    return [w if t == "sliding_attention" else None for t in types]
+
+
 def shapes(conf: dict) -> dict:
     d, H = conf["hidden_size"], conf["num_attention_heads"]
-    return dict(L=conf["num_hidden_layers"], d=d, H=H,
-                Hk=conf["num_key_value_heads"],
-                D=conf.get("head_dim") or d // H,
-                ff=conf["intermediate_size"], V=conf["vocab_size"],
-                tied=bool(conf["tie_word_embeddings"]))
+    s = dict(L=conf["num_hidden_layers"], d=d, H=H,
+             Hk=conf["num_key_value_heads"],
+             D=conf.get("head_dim") or d // H,
+             ff=conf["intermediate_size"], V=conf["vocab_size"],
+             tied=bool(conf["tie_word_embeddings"]), windows=windows(conf))
+    per_layer = (d * H * s["D"] + 2 * d * s["Hk"] * s["D"] + H * s["D"] * d
+                 + 3 * d * s["ff"])
+    # multiply and add count as two operations; the head is counted apart
+    s["mm_flops_per_token"] = 2 * s["L"] * per_layer
+    return s
+
+
+def model_config(conf: dict, s: dict):
+    """The served config. ``ModelConfig`` holds one window for every
+    layer, so a configuration whose layers differ in their window needs
+    a family of its own."""
+    from repro.models.config import ModelConfig
+    if len(set(s["windows"])) > 1:
+        raise ValueError(f"{conf['name']}: windows differ by layer "
+                         f"({s['windows']}); ModelConfig has one for all")
+    return ModelConfig(
+        name=conf["name"], family="dense", n_layers=s["L"], d_model=s["d"],
+        n_heads=s["H"], n_kv_heads=s["Hk"], head_dim=s["D"], d_ff=s["ff"],
+        vocab=s["V"], rope_theta=float(conf["rope_theta"]),
+        sliding_window=s["windows"][0], norm="rmsnorm",
+        act="swiglu", pos="rope", tie_embeddings=s["tied"])
 
 
 def jax_key(seed: int) -> jax.Array:
@@ -132,9 +176,21 @@ def _forward(params, tokens, *, conf_key, low):
     f32 = jnp.float32
     x = params["embed"]["table"][tokens].astype(f32)              # [T, d]
     causal = jnp.tril(jnp.ones((T, T), bool))
+    # key j is visible to row i when i - w < j <= i (Mistral); a window
+    # that spans the whole sequence leaves the causal mask as it is
+    bands = [w for w in s["windows"] if w is not None and w < T]
+    if bands:
+        wins = jnp.asarray([T if w is None else min(w, T)
+                            for w in s["windows"]], jnp.int32)
+        behind = jnp.arange(T)[:, None] - jnp.arange(T)[None, :]   # i - j
+        xs = (params["layers"], wins)
+    else:
+        xs = (params["layers"], None)
 
-    def layer(x, lp):
-        lp = jax.tree_util.tree_map(lambda w: w.astype(f32), lp)
+    def layer(x, xs):
+        lp, w = xs
+        visible = causal if w is None else causal & (behind < w)
+        lp = jax.tree_util.tree_map(lambda a: a.astype(f32), lp)
         h = _rms_norm(x, lp["ln1"]["scale"], eps)
         q = _rope(_mm("td,dhk->thk", h, lp["attn"]["wq"], low), theta)
         k = _rope(_mm("td,dhk->thk", h, lp["attn"]["wk"], low), theta)
@@ -142,7 +198,7 @@ def _forward(params, tokens, *, conf_key, low):
         k = jnp.repeat(k, H // Hk, axis=1)        # q head h reads kv h // g
         v = jnp.repeat(v, H // Hk, axis=1)
         sc = _mm("thk,shk->hts", q, k, low) * D ** -0.5
-        p = jax.nn.softmax(jnp.where(causal, sc, -jnp.inf), axis=-1)
+        p = jax.nn.softmax(jnp.where(visible, sc, -jnp.inf), axis=-1)
         a = _mm("hts,shk->thk", p, v, low)
         x = x + _mm("thk,hkd->td", a, lp["attn"]["wo"], low)
         h = _rms_norm(x, lp["ln2"]["scale"], eps)
@@ -151,7 +207,7 @@ def _forward(params, tokens, *, conf_key, low):
         x = x + _mm("tf,fd->td", jax.nn.silu(gate) * up, lp["mlp"]["w2"], low)
         return x, None
 
-    x, _ = jax.lax.scan(layer, x, params["layers"])
+    x, _ = jax.lax.scan(layer, x, xs)
     x = _rms_norm(x, params["final_norm"]["scale"], eps)
     head = params["embed" if s["tied"] else "lm_head"]["table"].astype(f32)
     return _mm("td,vd->tv", x, head, low)
@@ -160,8 +216,10 @@ def _forward(params, tokens, *, conf_key, low):
 def _conf_key(conf: dict) -> tuple:
     keys = ("hidden_size", "num_attention_heads", "num_key_value_heads",
             "num_hidden_layers", "intermediate_size", "vocab_size",
-            "rms_norm_eps", "rope_theta", "tie_word_embeddings", "head_dim")
-    return tuple((k, conf.get(k)) for k in keys)
+            "rms_norm_eps", "rope_theta", "tie_word_embeddings", "head_dim",
+            "sliding_window", "layer_types")
+    return tuple((k, tuple(v) if isinstance(v, list) else v)
+                 for k, v in ((k, conf.get(k)) for k in keys))
 
 
 def logits(conf: dict, params, tokens: np.ndarray, *, low: bool = False,

@@ -2,9 +2,12 @@
 
 A cell (an entry of ``BENCHMARK.json``'s ``workloads``) names a model
 configuration (``bench/configs/<config>.json``) and a traffic mix
-(``bench/traffic/<mix>.json``); every metric is a reader of its own
-(``bench/metrics/<metric>.py``). This module knows none of them by
-name.
+(``bench/traffic/<mix>.json``); the configuration names its model
+family (``"family"``, ``dense`` where it names none), whose module
+(``bench/families/<family>.py``) gives the served model's config, its
+weights, its shapes for the counts and the plain reference; every
+metric is a reader of its own (``bench/metrics/<metric>.py``). This
+module knows none of them by name.
 
 The run:
 
@@ -33,6 +36,7 @@ The run:
 from __future__ import annotations
 
 import dataclasses
+import functools
 import gc
 import importlib.util
 import json
@@ -42,6 +46,7 @@ import sys
 import time
 from dataclasses import dataclass, field
 from pathlib import Path
+from types import ModuleType
 from typing import Dict, List, Optional
 
 import numpy as np
@@ -50,7 +55,6 @@ BENCH = Path(__file__).resolve().parent
 if str(BENCH) not in sys.path:
     sys.path.insert(0, str(BENCH))
 
-import model as bmodel  # noqa: E402
 import reduce as breduce  # noqa: E402
 from traffic import generator as gen  # noqa: E402
 
@@ -68,6 +72,7 @@ class Cell:
     name: str
     chips: int
     conf: dict
+    family: ModuleType           # the configuration's family module
     mix: dict
     metrics: List[dict]          # end-to-end metrics this cell reports
     layer_metrics: List[dict]    # per-layer metrics this cell reports
@@ -93,17 +98,44 @@ def load_cell(root: Path, name: str) -> Cell:
     e2e = [m for m in spec["end_to_end"] if _applies(m, name, ())]
     names = {m["name"] for m in e2e}
     layer = [m for m in spec["per_layer"] if _applies(m, name, names)]
-    return Cell(name, int(w["chips"]), conf, mix, e2e, layer)
+    return Cell(name, int(w["chips"]), conf,
+                family(root, conf.get("family", "dense")), mix, e2e, layer)
+
+
+@functools.lru_cache(maxsize=None)
+def _module(path: Path) -> ModuleType:
+    """The module at ``path``, loaded once per process."""
+    spec = importlib.util.spec_from_file_location(
+        f"bench_{path.parent.name}_{path.stem}", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def family(root: Path, name: str) -> ModuleType:
+    """The family module ``bench/families/<name>.py``: ``shapes(conf)``,
+    ``model_config(conf, shapes)``, ``init_params(conf, seed, device)``
+    and ``logits(conf, params, tokens, *, low, pad_to)``.
+
+    It is imported as ``families.<name>`` from ``root``'s
+    ``bench/families``, once per process, so that a family that builds
+    on another (``from families import dense``) gets the very module the
+    harness runs, with the programs it has compiled."""
+    where = str(root.resolve() / "bench" / "families")
+    pkg = sys.modules.get("families")
+    if pkg is None or list(getattr(pkg, "__path__", ())) != [where]:
+        for key in [k for k in sys.modules
+                    if k == "families" or k.startswith("families.")]:
+            del sys.modules[key]
+        pkg = ModuleType("families")
+        pkg.__path__ = [where]
+        sys.modules["families"] = pkg
+    return importlib.import_module(f"families.{name}")
 
 
 def reader(root: Path, metric: str):
     """The ``read(run)`` function of ``bench/metrics/<metric>.py``."""
-    path = root / "bench" / "metrics" / f"{metric}.py"
-    spec = importlib.util.spec_from_file_location(
-        f"bench_metric_{metric.replace('.', '_').replace('-', '_')}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return _module(root / "bench" / "metrics" / f"{metric}.py").read
 
 
 # --- records the readers read -------------------------------------------------
@@ -251,37 +283,54 @@ class Tap:
                 self.step.free_pages = int(ev.data["value"])
 
 
+# the keys of a mix's ``engine`` block that the harness reads itself
+ENGINE_KEYS = ("max_len", "page_size", "prompt_buckets",
+               "chunk_prefill_tokens", "prefix_cache_pages")
+
+
+def engine_config(e: dict, kv_budget_tokens: int):
+    """The ``EngineConfig`` of a mix's ``engine`` block: the paged engine
+    with as many slots of ``max_len`` as the configuration's KV budget
+    holds beside the prefix cache's pages. Any other key of the block is
+    an ``EngineConfig`` field, passed on by name."""
+    from repro.serving.engine import EngineConfig
+    max_len, page = int(e["max_len"]), int(e["page_size"])
+    prefix_pages = int(e.get("prefix_cache_pages", 0))
+    own = dict(n_slots=(kv_budget_tokens - prefix_pages * page) // max_len,
+               max_len=max_len, prompt_buckets=tuple(e["prompt_buckets"]),
+               paged=True, page_size=page,
+               chunk_prefill_tokens=e.get("chunk_prefill_tokens"),
+               prefix_cache=prefix_pages > 0, prefix_cache_pages=prefix_pages)
+    rest = {k: v for k, v in e.items() if k not in ENGINE_KEYS}
+    fields = {f.name for f in dataclasses.fields(EngineConfig)}
+    bad = sorted(k for k in rest if k not in fields or k in own)
+    if bad:
+        raise ValueError(f"engine keys {bad} are not EngineConfig fields "
+                         f"that a mix may set")
+    return EngineConfig(**own, **rest)
+
+
 class Served:
     """The system under test: the serving engine with its scheduler,
-    built from the configuration and the mix."""
+    built from the configuration, its family and the mix."""
 
     def __init__(self, cell: Cell, conf: dict, seed: int, device,
                  clock) -> None:
         from repro.core.estimator import DriftConfig
         from repro.core.scheduler import DriftScheduler
-        from repro.models.config import ModelConfig
         from repro.obs import TraceRecorder
-        from repro.serving.engine import EngineConfig, ServingEngine
+        from repro.serving.engine import ServingEngine
 
-        s = bmodel.shapes(conf)
-        self.shapes = s
-        e, sc = cell.mix["engine"], cell.mix["scheduler"]
-        self.buckets = tuple(e["prompt_buckets"])
-        self.max_len = int(e["max_len"])
-        page = int(e["page_size"])
-        prefix_pages = int(e.get("prefix_cache_pages", 0))
-        self.prefix_cache = prefix_pages > 0
-        n_slots = ((conf["serving"]["kv_budget_tokens"] - prefix_pages * page)
-                   // self.max_len)
-        self.cfg = ModelConfig(
-            name=conf["name"], family="dense", n_layers=s["L"],
-            d_model=s["d"], n_heads=s["H"], n_kv_heads=s["Hk"],
-            head_dim=s["D"], d_ff=s["ff"], vocab=s["V"],
-            rope_theta=float(conf["rope_theta"]),
-            sliding_window=conf.get("sliding_window"), norm="rmsnorm",
-            act="swiglu", pos="rope",
-            tie_embeddings=bool(conf["tie_word_embeddings"]))
-        self.params = bmodel.init_params(conf, seed, device)
+        fam = cell.family
+        self.shapes = fam.shapes(conf)
+        sc = cell.mix["scheduler"]
+        ec = engine_config(cell.mix["engine"],
+                           conf["serving"]["kv_budget_tokens"])
+        self.buckets = ec.prompt_buckets
+        self.max_len = ec.max_len
+        self.prefix_cache = ec.prefix_cache
+        self.cfg = fam.model_config(conf, self.shapes)
+        self.params = fam.init_params(conf, seed, device)
         self.recs: Dict[int, ReqRec] = {}
         self.tap = Tap(clock, self.recs)
         sched = DriftScheduler(
@@ -289,19 +338,13 @@ class Served:
             config=DriftConfig(bias_enabled=bool(sc["bias_enabled"])),
             max_new_per_step=sc.get("max_new_per_step"))
         self.engine = ServingEngine(
-            self.cfg, self.params, sched,
-            EngineConfig(n_slots=n_slots, max_len=self.max_len,
-                         prompt_buckets=self.buckets, paged=True,
-                         page_size=page,
-                         chunk_prefill_tokens=e.get("chunk_prefill_tokens"),
-                         prefix_cache=self.prefix_cache,
-                         prefix_cache_pages=prefix_pages),
+            self.cfg, self.params, sched, ec,
             trace=TraceRecorder(capacity=1, observers=[self.tap]),
             device=device)
-        self.n_slots = n_slots
+        self.n_slots = ec.n_slots
         self.total_pages = self.engine.alloc.n_pages
         self.pages_per_seq = self.engine.alloc.pages_per_seq
-        self.page = page
+        self.page = ec.page_size
 
     # -- requests ----------------------------------------------------------
     def submit(self, spec: gen.Spec, due: float, in_window: bool,
@@ -673,7 +716,7 @@ def run(root: Path, workload: str, seed: int, seconds: float, trace: bool,
     finished = [r for r in served.tap.completed if rec_run.in_window(r.done)]
     params = served.params
     served.release()
-    checks, correct = check(conf, mix, params, finished, seed,
+    checks, correct = check(cell.family, conf, mix, params, finished, seed,
                             served.prefix_cache, served.max_len, log)
 
     metrics = {}
@@ -709,29 +752,30 @@ def sample(finished: List[ReqRec], seed: int, k: int) -> List[ReqRec]:
     return [longest] + [rest[i] for i in sorted(pick)]
 
 
-def gaps_of(conf: dict, params, rec: ReqRec, vocab: int, prefix_cache: bool,
-            pad_to: int, *, control: bool = False) -> np.ndarray:
-    """Per served token: how far the reference's logit of the token lies
-    below the reference's best. With ``control``, of the token that the
-    float8 control puts first at the same position."""
+def gaps_of(fam, conf: dict, params, rec: ReqRec, vocab: int,
+            prefix_cache: bool, pad_to: int, *,
+            control: bool = False) -> np.ndarray:
+    """Per served token: how far the family's reference logit of the
+    token lies below the reference's best. With ``control``, of the token
+    that the family's control puts first at the same position."""
     ids = prompt_ids(rec.spec, rec.bucket, vocab, prefix_cache)
     seq = np.concatenate([ids, np.asarray(rec.tokens[:-1], np.int32)])
     rows = slice(rec.bucket - 1, rec.bucket - 1 + len(rec.tokens))
-    ref = bmodel.logits(conf, params, seq, pad_to=pad_to)[rows]
+    ref = fam.logits(conf, params, seq, pad_to=pad_to)[rows]
     if control:
-        low = bmodel.logits(conf, params, seq, low=True, pad_to=pad_to)[rows]
+        low = fam.logits(conf, params, seq, low=True, pad_to=pad_to)[rows]
         picked = low.argmax(-1)
     else:
         picked = np.asarray(rec.tokens)
     return ref.max(-1) - ref[np.arange(len(picked)), picked]
 
 
-def check(conf: dict, mix: dict, params, finished: List[ReqRec], seed: int,
-          prefix_cache: bool, max_len: int, log) -> tuple:
+def check(fam, conf: dict, mix: dict, params, finished: List[ReqRec],
+          seed: int, prefix_cache: bool, max_len: int, log) -> tuple:
     """Hold the served tokens of a seeded sample of finished requests to
-    the plain reference."""
+    the family's plain reference."""
     limit = float(conf["check"]["max_logit_gap"])
-    vocab = bmodel.shapes(conf)["V"]
+    vocab = fam.shapes(conf)["V"]
     picked = sample(finished, seed, int(mix["check"]["sample"]))
     # a request whose token count disagrees with what the engine
     # reported, or that holds a token outside the vocabulary
@@ -743,7 +787,7 @@ def check(conf: dict, mix: dict, params, finished: List[ReqRec], seed: int,
     for r in picked:
         if bad:
             break
-        g = gaps_of(conf, params, r, vocab, prefix_cache, max_len)
+        g = gaps_of(fam, conf, params, r, vocab, prefix_cache, max_len)
         widest = max(widest, float(g.max()))
         n_tokens += len(g)
     ok = bool(picked) and bad == 0 and widest <= limit

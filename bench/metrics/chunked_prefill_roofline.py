@@ -1,8 +1,9 @@
 """Kernels: the fused chunked-prefill kernel's least time on the chip's
 roofline, from the bytes and operations that each launch's prompt
-tokens require (bucket padding is not required work), over its device
-time in the profiler trace, %."""
-from counting import chunked_prefill_call, least_seconds
+tokens require in each layer's window (bucket padding is not required
+work), summed over the layers, over its device time in the profiler
+trace, %."""
+from counting import chunked_prefill_call, least_seconds, over_layers
 
 # the kernel's device time: the Pallas calls inside the engine's
 # chunked-prefill program (its jitted inner ``fn``), which calls no other
@@ -18,7 +19,8 @@ def read(run):
     if not spent or not launches:
         return None
     # the prompt's own tokens end where the launch ends
-    least = sum(least_seconds(*chunked_prefill_call(run.shapes, q0 + c - n,
-                                                    n), run.peaks)
-                for q0, c, n in launches)
-    return 100.0 * run.shapes["L"] * least / spent
+    least = over_layers(run.shapes, lambda w: sum(
+        least_seconds(*chunked_prefill_call(run.shapes, q0 + c - n, n, w),
+                      run.peaks)
+        for q0, c, n in launches))
+    return 100.0 * least / spent

@@ -1,7 +1,7 @@
 """Model step: model operations that the window's decoded tokens
 require (projections, MLP, head, and attention over each token's live
-context) over the wall time of the steps that decoded times the chip's
-bf16 peak, %."""
+context inside each layer's window, summed over the layers) over the
+wall time of the steps that decoded times the chip's bf16 peak, %."""
 from counting import decode_token_flops
 from readings import step_seconds
 

@@ -1,7 +1,8 @@
 """Kernels: the batched paged-decode kernel's least time on the chip's
-roofline, from the bytes and operations its live lengths require (not
-the padded page grid), over its device time in the profiler trace, %."""
-from counting import least_seconds, paged_decode_call
+roofline, from the bytes and operations its live lengths require in each
+layer's window (not the padded page grid), summed over the layers, over
+its device time in the profiler trace, %."""
+from counting import least_seconds, over_layers, paged_decode_call
 
 # the kernel's device time: the Pallas calls inside the engine's decode
 # program, which calls no other kernel
@@ -15,6 +16,7 @@ def read(run):
     steps = [s for s in run.window_steps if s.decode]
     if not spent or not steps:
         return None
-    least = sum(least_seconds(*paged_decode_call(run.shapes, s.decode),
-                              run.peaks) for s in steps)
-    return 100.0 * run.shapes["L"] * least / spent
+    least = over_layers(run.shapes, lambda w: sum(
+        least_seconds(*paged_decode_call(run.shapes, s.decode, w), run.peaks)
+        for s in steps))
+    return 100.0 * least / spent

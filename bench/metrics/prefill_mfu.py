@@ -1,7 +1,8 @@
 """Model step: model operations that the window's uncached prompt
-tokens require (each attending the positions before it, the head once
-per launch) over the wall time of the steps that launched a prefill
-times the chip's bf16 peak, %. Bucket padding is not required work."""
+tokens require (each attending the positions before it inside each
+layer's window, summed over the layers, the head once per launch) over
+the wall time of the steps that launched a prefill times the chip's
+bf16 peak, %. Bucket padding is not required work."""
 from counting import prefill_flops
 from readings import step_seconds
 

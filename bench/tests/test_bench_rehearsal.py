@@ -113,6 +113,96 @@ def test_a_cell_added_as_files_is_found_by_name(tmp_path, short_warmup):
     assert out["correct"] is True
 
 
+FAMILY = '''"""A dense decoder whose residual projections start scaled by
+(2 L) ** -0.5, as GPT-2 initialises them."""
+from families import dense
+
+shapes, logits = dense.shapes, dense.logits
+
+
+def model_config(conf, s):
+    """Served with no window in any layer: ModelConfig holds one window
+    for all layers."""
+    return dense.model_config(conf, dict(s, windows=[None] * s["L"]))
+
+
+def init_params(conf, seed, device=None):
+    p = dense.init_params(conf, seed, device)
+    scale = (2 * conf["num_hidden_layers"]) ** -0.5
+    attn, mlp = p["layers"]["attn"], p["layers"]["mlp"]
+    attn["wo"], mlp["w2"] = attn["wo"] * scale, mlp["w2"] * scale
+    return p
+'''
+
+
+def tree(root: Path) -> dict:
+    return {p.relative_to(root): p.read_bytes() for p in root.rglob("*")
+            if p.is_file() and "__pycache__" not in p.parts}
+
+
+# the new family serves every layer without a window and its reference
+# windows alternate layers: it accepts what is served where no served
+# position reaches the window (the mix's max_len), and refuses it where
+# the served sequences pass a window of 8, so the check reads the
+# family's own reference
+@pytest.mark.parametrize("window,correct", [(1024, True), (8, False)])
+def test_a_configuration_added_as_files_is_found_by_name(
+        tmp_path, short_warmup, window, correct):
+    shutil.copytree(BENCH, tmp_path / "bench",
+                    ignore=shutil.ignore_patterns("tests", "__pycache__"))
+    new = {Path("bench/families/dense_scaled.py"): FAMILY.encode()}
+    conf = json.loads((BENCH / "configs" / "smollm-135m.json").read_text())
+    L = conf["smoke"]["num_hidden_layers"]
+    conf.update(name="smollm-135m-scaled", family="dense_scaled")
+    conf["smoke"] = dict(conf["smoke"], sliding_window=window, layer_types=[
+        ("full_attention", "sliding_attention")[i % 2] for i in range(L)])
+    new[Path("bench/configs/smollm-135m-scaled.json")] = json.dumps(
+        conf).encode()
+    mix = json.loads((BENCH / "traffic" / "chat-poisson.json").read_text())
+    mix["arrivals"]["rate_rps"] = 3.0
+    new[Path("bench/traffic/chat-slow.json")] = json.dumps(mix).encode()
+    for path, data in new.items():
+        (tmp_path / path).write_bytes(data)
+    spec = dict(SPEC, configs=SPEC["configs"] + [{
+        "name": "smollm-135m-scaled", "source": "a test",
+        "file": "bench/configs/smollm-135m-scaled.json", "reduced": [],
+        "why": "a dense variant"}], workloads=SPEC["workloads"] + [{
+            "name": "scaled-chat-slow", "config": "smollm-135m-scaled",
+            "traffic": "chat-slow", "chips": 1, "why": "a new family"}])
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(spec))
+
+    cell = harness.load_cell(tmp_path, "scaled-chat-slow")
+    assert cell.family.__file__ == str(tmp_path / "bench" / "families"
+                                       / "dense_scaled.py")
+    s = cell.family.shapes(dict(cell.conf, **cell.conf["smoke"]))
+    assert s["windows"] == [None, window] * (L // 2)
+    out = rehearse(tmp_path, "scaled-chat-slow", seconds=2.0)
+    assert out["attempted"] == 6          # 3 requests/s for 2 s
+    assert out["checks"]["requests_compared"]["value"] >= 1
+    assert out["correct"] is correct, out["checks"]
+    # the new files, and no other file of the benchmark changed
+    copied = tree(tmp_path / "bench")
+    repo = {p: b for p, b in tree(BENCH).items() if p.parts[0] != "tests"}
+    assert {Path("bench") / p for p in set(copied) - set(repo)} == set(new)
+    assert all(copied[p] == b for p, b in repo.items())
+
+
+def test_engine_keys_by_name():
+    e = json.loads((BENCH / "traffic" / "rag-closed.json").read_text())[
+        "engine"]
+    ec = harness.engine_config(e, 65536)
+    assert (ec.n_slots, ec.max_len, ec.prompt_buckets, ec.paged,
+            ec.prefix_cache, ec.prefix_cache_pages) == (
+        23, 2304, (1024, 1280, 1536, 1792, 2048), True, True, 768)
+    # another EngineConfig field passes through by name
+    assert harness.engine_config(dict(e, batch_wait_steps=2),
+                                 65536).batch_wait_steps == 2
+    # a key that is no field, or one the harness works out itself
+    for key in ("page_size_tokens", "n_slots", "paged", "prefix_cache"):
+        with pytest.raises(ValueError, match=key):
+            harness.engine_config(dict(e, **{key: 1}), 65536)
+
+
 def test_no_chip_no_result(tmp_path):
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     cmd = [sys.executable, str(ROOT / "bench" / "run.py"), "--workload",
