@@ -165,12 +165,16 @@ class ReqRec:
 
 @dataclass
 class StepRec:
+    """One ``ServingEngine.step``. ``gauges`` holds every ``gauge`` event
+    the program emitted during the step, by its ``name``: the last value
+    of that name, a number or a list of numbers, as emitted."""
+
     start: float
     end: float = 0.0
     launches: int = 0                     # n_prefill_launches delta
     prefill: List[tuple] = field(default_factory=list)   # (q0, c, n_real)
     decode: List[int] = field(default_factory=list)      # kv_len per token
-    free_pages: Optional[int] = None
+    gauges: Dict[str, object] = field(default_factory=dict)
 
 
 @dataclass
@@ -245,7 +249,7 @@ def prompt_ids(spec: gen.Spec, bucket: int, vocab: int,
 
 class Tap:
     """Observer of the engine's trace: stamps tokens on the wall clock
-    and files prefill launches, decode contexts and page gauges under
+    and files prefill launches, decode contexts and every gauge under
     the step in progress."""
 
     def __init__(self, clock, recs: Dict[int, ReqRec]) -> None:
@@ -278,9 +282,8 @@ class Tap:
         elif kind == "complete":
             rec.done = self.clock()
             self.completed.append(rec)
-        elif kind == "gauge" and ev.data.get("name") == "kv_free_pages":
-            if self.step is not None:
-                self.step.free_pages = int(ev.data["value"])
+        elif kind == "gauge" and self.step is not None:
+            self.step.gauges[ev.data["name"]] = ev.data["value"]
 
 
 # the keys of a mix's ``engine`` block that the harness reads itself

@@ -5,16 +5,15 @@ work), summed over the layers, over its device time in the profiler
 trace, %."""
 from counting import chunked_prefill_call, least_seconds, over_layers
 
-# the kernel's device time: the Pallas calls inside the engine's
-# chunked-prefill program (its jitted inner ``fn``), which calls no other
-# kernel
-PROGRAM = "jit_fn"
+# the kernel's device time: its own Pallas calls inside the engine's
+# chunked-prefill program (its jitted inner ``fn``)
+PROGRAM, KERNEL = "jit_fn", "chunked_prefill_attention"
 
 
 def read(run):
     if run.device is None:
         return None
-    spent = run.device.kernel_s(PROGRAM)
+    spent = run.device.kernel_s(PROGRAM, KERNEL)
     launches = [p for s in run.window_steps for p in s.prefill]
     if not spent or not launches:
         return None

@@ -4,7 +4,8 @@ window's steps, %."""
 
 
 def read(run):
-    free = [s.free_pages for s in run.window_steps if s.free_pages is not None]
+    free = [s.gauges["kv_free_pages"] for s in run.window_steps
+            if "kv_free_pages" in s.gauges]
     if not free:
         return None
     return 100.0 * (1.0 - sum(free) / (len(free) * run.total_pages))

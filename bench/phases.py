@@ -9,9 +9,9 @@ then one JSON line, ``{"phases": ...}``, read from the same profile:
 * ``idle_s_by_span``: the device's idle time in the window, each instant
   under the innermost host span open then among the harness's
   (``bench.*``) and the program's (``engine.*``, ``sched.*``; see
-  ``repro.obs.spans``); ``(no span)`` where none is open. The spans of
-  one thread nest, so one sweep over their boundaries finds the
-  innermost;
+  ``repro.obs.spans``); ``(no span)`` where none is open. This is the
+  reduction's own split (``reduce.py``), which the result line's
+  ``breakdown`` lists as ``idle_gaps``, in full;
 * ``engine_host_idle_share``: the idle time under ``engine.*`` and
   ``sched.*`` spans over the window, %: the part of the device's idle
   share that the program's own host work causes;
@@ -23,9 +23,8 @@ then one JSON line, ``{"phases": ...}``, read from the same profile:
   times, not the ``jax.named_scope`` path of the operation, so this is
   the finest split of the copies the trace itself gives.
 
-The result line's own ``breakdown`` is the benchmark's reduction
-(``reduce.py``), unchanged. Without the program's spans (an older
-program) the idle time falls under the harness's spans alone.
+Without the program's spans (an older program) the idle time falls under
+the harness's spans alone.
 """
 
 from __future__ import annotations
@@ -37,7 +36,7 @@ import sys
 import time
 from collections import defaultdict
 from pathlib import Path
-from typing import Dict, List, Tuple
+from typing import Dict, Tuple
 
 ROOT = Path(__file__).resolve().parents[1]
 BENCH = Path(__file__).resolve().parent
@@ -46,75 +45,7 @@ if str(BENCH) not in sys.path:
 
 import reduce as breduce  # noqa: E402
 
-PREFIXES = ("bench.", "engine.", "sched.")
 PROGRAM = ("engine.", "sched.")
-NO_SPAN = "(no span)"
-
-Span = Tuple[float, float, str]
-
-
-def innermost(spans: List[Span]) -> List[Span]:
-    """The union of properly nested ``(start, end, name)`` spans cut into
-    pieces, each named after the innermost span open over it. A span
-    that outlasts its parent is cut at the parent's end."""
-    pieces: List[Span] = []
-    stack: List[List] = []                # [end, name], innermost last
-    t = float("-inf")
-
-    def close_until(a: float) -> None:
-        nonlocal t
-        while stack and stack[-1][0] <= a:
-            end, name = stack.pop()
-            if end > t:
-                pieces.append((t, end, name))
-                t = end
-
-    for a, b, name in sorted(spans, key=lambda s: (s[0], -s[1])):
-        close_until(a)
-        if stack:
-            if a > t:
-                pieces.append((t, a, stack[-1][1]))
-            b = min(b, stack[-1][0])
-        t = a
-        stack.append([b, name])
-    close_until(float("inf"))
-    return pieces
-
-
-def split(intervals: List[Tuple[float, float]], pieces: List[Span]
-          ) -> Dict[str, float]:
-    """Length of the ``intervals`` under each piece's name; what no piece
-    covers goes under ``(no span)``. Both lists are swept once."""
-    out: Dict[str, float] = defaultdict(float)
-    pieces = sorted(pieces)
-    i = 0
-    for a, b in sorted(intervals):
-        while i < len(pieces) and pieces[i][1] <= a:
-            i += 1
-        covered = 0.0
-        j = i
-        while j < len(pieces) and pieces[j][0] < b:
-            p0, p1, name = pieces[j]
-            ov = min(b, p1) - max(a, p0)
-            if ov > 0:
-                out[name] += ov
-                covered += ov
-            j += 1
-        if b - a > covered:
-            out[NO_SPAN] += b - a - covered
-    return dict(out)
-
-
-def host_spans(pd, window_span: str = "bench.window") -> List[Span]:
-    """The harness's and the program's spans on the thread that opened
-    the window."""
-    host = next(p for p in pd.planes if p.name == breduce.HOST_PLANE)
-    for line in host.lines:
-        events = [(ev.start_ns, ev.start_ns + ev.duration_ns, ev.name)
-                  for ev in line.events if ev.name.startswith(PREFIXES)]
-        if any(n == window_span for _, _, n in events):
-            return events
-    raise ValueError(f"trace has no span {window_span!r}")
 
 
 def result_type(event: str) -> str:
@@ -131,10 +62,10 @@ def phases(pd, red: breduce.Reduced, *, window_span: str = "bench.window",
     v = dict(breduce.TPU_VIEW)
     v.update(view)
     lo, hi = red.window
-    spans = [s for s in host_spans(pd, window_span) if s[2] != window_span]
-    pieces = [(max(a, lo), min(b, hi), n) for a, b, n in innermost(spans)
-              if b > lo and a < hi]
-    idle: Dict[str, float] = defaultdict(float)
+    spans = [s for s in breduce.host_spans(pd, window_span)
+             if s[2] != window_span]
+    pieces = [(max(a, lo), min(b, hi), n)
+              for a, b, n in breduce.innermost(spans) if b > lo and a < hi]
     copy_ns: Dict[Tuple[str, str], float] = defaultdict(float)
     n_planes = 0
     for plane in pd.planes:
@@ -145,9 +76,6 @@ def phases(pd, red: breduce.Reduced, *, window_span: str = "bench.window",
         if not progs:
             continue
         n_planes += 1
-        busy = breduce.union([(a, b) for a, b, _ in progs])
-        for name, ns in split(breduce.gaps(busy, lo, hi), pieces).items():
-            idle[name] += ns
         ops = sorted(breduce._events(plane, v["op_line"], lo, hi,
                                      v["op_event"]))
         i = 0
@@ -166,6 +94,7 @@ def phases(pd, red: breduce.Reduced, *, window_span: str = "bench.window",
     self_ns: Dict[str, float] = defaultdict(float)
     for a, b, name in pieces:
         self_ns[name] += b - a
+    idle = red.gaps_by_span
     program_idle = sum(ns for n, ns in idle.items() if n.startswith(PROGRAM))
 
     def by_value(d):
@@ -174,9 +103,8 @@ def phases(pd, red: breduce.Reduced, *, window_span: str = "bench.window",
     return {
         "window_s": (hi - lo) / 1e9,
         "engine_steps": steps,
-        "idle_s_by_span": {k: ns * scale for k, ns in by_value(idle)},
-        "engine_host_idle_share": 100.0 * program_idle * scale
-        / ((hi - lo) / 1e9),
+        "idle_s_by_span": {k: ns / 1e9 for k, ns in by_value(idle)},
+        "engine_host_idle_share": 100.0 * program_idle / (hi - lo),
         "host_ms_per_step": ({k: ns / 1e6 / steps
                               for k, ns in by_value(self_ns)}
                              if steps else {}),

@@ -140,8 +140,13 @@ def test_no_window_binds_in_todays_cells(cell):
         "TPU v5 lite"]
     steps = [SimpleNamespace(decode=[max_len, 3, 100], prefill=[(0, 64, 40)]),
              SimpleNamespace(decode=[17], prefill=[(96, 160, 160)])]
+    # each roofline reads its own kernel's time
+    own = {("jit__decode_paged_fn", "batched_paged_decode_attention"),
+           ("jit_fn", "chunked_prefill_attention")}
     run = SimpleNamespace(shapes=s, peaks=peaks, window_steps=steps,
-                          device=SimpleNamespace(kernel_s=lambda _: 1e-3))
+                          device=SimpleNamespace(
+                              kernel_s=lambda p, k: 1e-3 if (p, k) in own
+                              else None))
     least = {
         "paged_decode_roofline": sum(c.least_seconds(*single_layer(
             s, kv_lens=st.decode), peaks) for st in steps),

@@ -49,14 +49,19 @@ def state_unchanged(monkeypatch):
 
 
 def half_batch(monkeypatch):
-    """The decode step leaves out the second half of the slots: their
-    logits are never computed."""
+    """The decode step leaves out half of the slots, those whose sequence
+    holds an even number of tokens: their logits are never computed. A
+    sequence grows by one token a step, so every request that decodes
+    twice is left out at one of those steps, whichever slots the engine
+    fills and however few steps the load lets it take."""
+    import jax.numpy as jnp
     from repro.models import transformer
     step = transformer.decode_step_paged
 
-    def broken(*a, **kw):
-        logits, pool = step(*a, **kw)
-        return logits.at[logits.shape[0] // 2:].set(0.0), pool
+    def broken(cfg, params, pool, tokens, page_table, seq_lens, **kw):
+        logits, pool = step(cfg, params, pool, tokens, page_table, seq_lens,
+                            **kw)
+        return jnp.where((seq_lens % 2 == 0)[:, None], 0.0, logits), pool
 
     monkeypatch.setattr(transformer, "decode_step_paged", broken)
 

@@ -23,7 +23,7 @@ def test_idle_split_by_innermost_span_by_hand():
     spans = [(2.5, 4.5, "bench.step"), (4.5, 9.0, "bench.wait")]
     # (3, 5): 1.5 under the step, 0.5 under the wait; (8, 10): 1 under
     # the wait, 1 under no span
-    assert ph.split([(3, 5), (8, 10)], ph.innermost(spans)) == {
+    assert r.split([(3, 5), (8, 10)], r.innermost(spans)) == {
         "bench.step": 1.5, "bench.wait": 1.5, "(no span)": 1}
 
 
@@ -32,20 +32,20 @@ def test_a_gap_that_crosses_into_sibling_spans_is_split():
              (10, 14, "engine.retire"), (20, 24, "bench.release"),
              (24, 40, "bench.step"), (25, 35, "engine.step"),
              (25, 27, "engine.admit")]
-    assert ph.innermost(spans) == [
+    assert r.innermost(spans) == [
         (0, 1, "bench.step"), (1, 10, "engine.step"),
         (10, 14, "engine.retire"), (14, 15, "engine.step"),
         (15, 20, "bench.step"), (20, 24, "bench.release"),
         (24, 25, "bench.step"), (25, 27, "engine.admit"),
         (27, 35, "engine.step"), (35, 40, "bench.step")]
-    got = ph.split([(12, 26), (41, 43)], ph.innermost(spans))
+    got = r.split([(12, 26), (41, 43)], r.innermost(spans))
     assert got == {"engine.retire": 2, "engine.step": 1, "bench.step": 6,
                    "bench.release": 4, "engine.admit": 1, "(no span)": 2}
 
 
 def test_a_span_that_outlasts_its_parent_is_cut_at_its_end():
     spans = [(0, 10, "engine.step"), (7, 12, "engine.retire")]
-    assert ph.innermost(spans) == [(0, 7, "engine.step"),
+    assert r.innermost(spans) == [(0, 7, "engine.step"),
                                    (7, 10, "engine.retire")]
 
 
@@ -128,3 +128,7 @@ def test_a_rehearsed_cell_puts_its_idle_time_under_engine_phases(
             "engine.retire"} <= set(rep["host_ms_per_step"])
     share = out["metrics"]["device_idle_share"]["value"]
     assert 0 < rep["engine_host_idle_share"] <= share
+    # the result line's breakdown names the engine's phases
+    gaps = dict(out["breakdown"]["idle_gaps"])
+    assert any(n.startswith("engine.") for n in gaps)
+    assert all(idle[n] == pytest.approx(s) for n, s in gaps.items())

@@ -4,12 +4,26 @@ trace recorded on the CPU."""
 import sys
 import time
 from pathlib import Path
+from types import SimpleNamespace
 
 import pytest
 
 sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
 
 import reduce as r  # noqa: E402
+
+
+def synthetic(host, programs, ops):
+    """A profile with one TPU: ``host`` spans on the thread that opens
+    the window, ``programs`` and ``ops``, each as ``(start, end, name)``."""
+    def line(name, events):
+        return SimpleNamespace(name=name, events=[
+            SimpleNamespace(name=n, start_ns=a, duration_ns=b - a)
+            for a, b, n in events])
+    return SimpleNamespace(planes=[
+        SimpleNamespace(name=r.HOST_PLANE, lines=[line("main", host)]),
+        SimpleNamespace(name="/device:TPU:0", lines=[
+            line("XLA Modules", programs), line("XLA Ops", ops)])])
 
 
 def test_union_gaps_and_attribution_by_hand():
@@ -20,9 +34,67 @@ def test_union_gaps_and_attribution_by_hand():
     spans = [(2.5, 4.5, "bench.step"), (4.5, 9.0, "bench.wait")]
     # (3, 5): 1.5 under the step, 0.5 under the wait; (8, 10): 1 under
     # the wait, 1 under no span
-    assert r.attribute([(3, 5), (8, 10)], spans) == {"bench.step": 2,
-                                                      "bench.wait": 2}
-    assert r.attribute([(10, 11)], spans) == {"(no span)": 1}
+    pd = synthetic([(0, 10, "bench.window")] + spans,
+                   [(a, b, "jit_f(1)") for a, b in busy], [])
+    red = r.reduce_profile(pd)
+    assert red.gaps_by_span == {"bench.step": 1.5, "bench.wait": 1.5,
+                                "(no span)": 1}
+    assert red.busy_ns == 6 and red.window == (0, 10)
+    assert r.split([(10, 11)], r.innermost(spans)) == {"(no span)": 1}
+
+
+def custom_call(kernel, n):
+    return (f'%{kernel}.{n} = bf16[8,3,3,64] custom-call(s32[8] %a), '
+            f'custom_call_target="tpu_custom_call", backend_config="..."')
+
+
+def test_each_kernel_keeps_its_own_time():
+    """Two Pallas calls in one program, and idle time under the engine's
+    phases."""
+    host = [(0, 100, "bench.window"), (0, 90, "bench.step"),
+            (0, 90, "engine.step"), (0, 10, "engine.decode_prep"),
+            (10, 12, "engine.decode_launch"), (12, 60, "engine.decode_sync"),
+            (60, 80, "engine.prefill"), (80, 90, "engine.retire"),
+            (90, 100, "bench.release")]
+    programs = [(10, 50, "jit__decode_paged_fn(17)"), (62, 78, "jit_fn(23)")]
+    ops = [(10, 50, "%while.4 = (s32[]) while((s32[]) %t)"),
+           (11, 16, custom_call("batched_paged_decode_attention", 4)),
+           (16, 40, "%copy.72 = bf16[8193,3,16,64]{3,2,1,0} copy(bf16[8193,"
+            "3,16,64]{3,1,2,0} %fusion.134)"),
+           (40, 45, custom_call("batched_paged_decode_attention", 4)),
+           (62, 66, custom_call("chunked_prefill_attention", 3)),
+           (66, 73, "ROOT " + custom_call("grouped_expert_matmul", 7)),
+           (73, 75, custom_call("chunked_prefill_attention", 3))]
+    red = r.reduce_profile(synthetic(host, programs, ops))
+    s = 1e-9
+    assert red.kernel_s("jit__decode_paged_fn",
+                        "batched_paged_decode_attention") == pytest.approx(
+        10 * s)
+    assert red.kernel_s("jit_fn", "chunked_prefill_attention") == (
+        pytest.approx(6 * s))
+    assert red.kernel_s("jit_fn", "grouped_expert_matmul") == (
+        pytest.approx(7 * s))
+    # every kernel of the program
+    assert red.kernel_s("jit_fn") == pytest.approx(13 * s)
+    assert red.kernel_s("jit__decode_paged_fn") == pytest.approx(10 * s)
+    assert red.kernel_s("jit_fn", "batched_paged_decode_attention") is None
+    assert red.kernel_s("jit__decode_paged_fn",
+                        "chunked_prefill_attention") is None
+    # the breakdown names kernels, never the kind they share
+    assert red.top_ops() == [
+        ["jit__decode_paged_fn: copy", pytest.approx(24 * s)],
+        ["jit__decode_paged_fn: batched_paged_decode_attention",
+         pytest.approx(10 * s)],
+        ["jit_fn: grouped_expert_matmul", pytest.approx(7 * s)],
+        ["jit_fn: chunked_prefill_attention", pytest.approx(6 * s)]]
+    # idle: [0, 10) prep, [50, 60) the wait for the tokens, [60, 62) and
+    # [78, 80) the prefill's host work, [80, 90) retire, [90, 100) release
+    assert red.gaps_by_span == {
+        "engine.decode_prep": 10, "engine.decode_sync": 10,
+        "engine.prefill": 4, "engine.retire": 10, "bench.release": 10}
+    assert sum(red.gaps_by_span.values()) == 100 - red.busy_ns
+    assert red.top_gaps(2) == [["engine.decode_prep", 10 * s],
+                               ["engine.decode_sync", 10 * s]]
 
 
 def test_names_of_programs_and_operations():
@@ -33,6 +105,10 @@ def test_names_of_programs_and_operations():
     assert r.op_kind('%closed_call.8 = bf16[128,3,3,64] custom-call(s32[128]'
                      ' %a), custom_call_target="tpu_custom_call"') == r.KERNEL
     assert r.op_kind("%while.4 = (s32[]) while((s32[]) %t)") == "while"
+    # a Pallas call's instruction is named after its kernel
+    assert r.instruction_name(custom_call("chunked_prefill_attention", 3)) == (
+        "chunked_prefill_attention")
+    assert r.instruction_name("ROOT " + custom_call("k", 12)) == "k"
 
 
 @pytest.fixture(scope="module")
@@ -81,7 +157,7 @@ def test_reduction_of_a_recorded_cpu_trace(cpu_trace):
                                         abs=5000)
     assert 0 < red.busy_s < red.window_s
     dot = next(n for n, _, _ in inside if n.startswith("dot"))
-    assert red.kernel_s(dot, r.op_kind(dot)) == pytest.approx(
+    assert red.op_ns[(dot, r.op_kind(dot))] / 1e9 == pytest.approx(
         sum(d for n, _, d in inside if n == dot) / 1e9)
     assert red.kernel_s("no such program") is None
     # idle time is split among the harness's spans and adds up

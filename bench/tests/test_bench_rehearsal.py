@@ -87,7 +87,8 @@ def test_per_layer_metrics_from_a_cpu_trace(short_warmup):
     # what needs no chip: host spans, the program's counters, the trace;
     # shares of a chip's peaks are left out without the chip's peaks
     peaks = {"decode_mfu", "prefill_mfu", "paged_decode_roofline",
-             "chunked_prefill_roofline", "peak_hbm_share"}
+             "chunked_prefill_roofline", "peak_hbm_share",
+             "prefill_mfu.rag", "chunked_prefill_roofline.rag"}
     want = {x["name"] for x in harness.load_cell(ROOT, cell).layer_metrics}
     assert set(m) == want - peaks
     assert 0 < m["prefix_hit_token_share"]["value"] < 100
